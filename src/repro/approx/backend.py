@@ -160,11 +160,16 @@ def _quantized_matmul(x2d: jax.Array, w: jax.Array,
     bits = backend.consts.get("bits", 8)
     qp_a = calibrate(x2d, bits=bits)
     qp_w = calibrate(w, bits=bits)
-    qa = quantize(x2d, qp_a)
-    qw = quantize(w, qp_w)
+    # The datapath's integer result is exact on every backend.  Fencing
+    # it (codes in, accumulator out) keeps the float code on both sides
+    # compiled alike whichever datapath fills the fence: on a TPU, XLA
+    # otherwise fuses quantize/dequant into the datapath's own ops
+    # differently per datapath, and the rounding of what follows drifts.
+    qa, qw = jax.lax.optimization_barrier(
+        (quantize(x2d, qp_a), quantize(w, qp_w)))
     za, zw = qp_a.zero_point, qp_w.zero_point
     k = x2d.shape[1]
-    s = dp.forward_q(qa, qw, backend.consts)
+    s = jax.lax.optimization_barrier(dp.forward_q(qa, qw, backend.consts))
     if dp.exact_int32:
         # exact datapath: Σ (qa-za)(qw-zw) with int32 accumulation
         row = jnp.sum(qa, axis=1, dtype=jnp.int32)        # (M,)

@@ -395,14 +395,23 @@ def conv2d(policy: ApproxPolicy, name: str, x: jax.Array, w: jax.Array,
     x: (B,H,W,Cin), w: (kh,kw,Cin,Cout).
     """
     kh, kw, cin, cout = w.shape
-    patches = jax.lax.conv_general_dilated_patches(
-        x, (kh, kw), (stride, stride), padding,
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-    )  # (B, Ho, Wo, kh*kw*cin) with feature dim ordered (cin, kh, kw)
-    bsz, ho, wo, feat = patches.shape
-    # conv_general_dilated_patches yields features ordered as
-    # (cin, kh, kw); reorder w to match.
-    w2d = jnp.transpose(w, (2, 0, 1, 3)).reshape(cin * kh * kw, cout)
+    bsz, h, wd, _ = x.shape
+    ho = conv_output_size(h, kh, stride, padding)
+    wo = conv_output_size(wd, kw, stride, padding)
+    # patches by strided slices: exact copies of the activations.  (As a
+    # convolution with a 0/1 kernel a TPU would run them in bf16 unless
+    # asked for HIGHEST precision, which costs most of the compile time
+    # of a banked all-layers program.)
+    pads = jax.lax.padtype_to_pads((h, wd), (kh, kw), (stride, stride),
+                                   padding)
+    xp = jnp.pad(x, ((0, 0), *pads, (0, 0)))
+    patches = jnp.stack(
+        [xp[:, i:i + (ho - 1) * stride + 1:stride,
+            j:j + (wo - 1) * stride + 1:stride, :]
+         for i in range(kh) for j in range(kw)], axis=-1)
+    # features ordered (cin, kh, kw); reorder w to match
+    feat = cin * kh * kw
+    w2d = jnp.transpose(w, (2, 0, 1, 3)).reshape(feat, cout)
     y = policy.matmul(name, patches.reshape(-1, feat), w2d)
     y = y.reshape(bsz, ho, wo, cout)
     if b is not None:

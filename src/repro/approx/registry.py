@@ -15,7 +15,8 @@ Built-in datapaths registered here:
   * ``lut``     — bit-true 256x256 LUT emulation (TFApprox port)
   * ``lowrank`` — rank-R factored LUT: R table lookups + R MXU matmuls
 
-Pallas variants (``lut_pallas``, ``lowrank_pallas``) are registered by
+Pallas variants (``lut_pallas``, ``lut_fused``; ``lowrank_pallas`` is
+the ``lowrank`` datapath under that name) are registered by
 ``repro.kernels.datapaths`` and resolved lazily on first lookup, so the
 core package never imports the kernel layer eagerly.
 """
@@ -166,7 +167,11 @@ def pack_lowrank(spec, library) -> dict:
 # ----------------------------------------------------------------------
 @register_datapath("int8")
 class Int8Datapath(Datapath):
-    """Exact Σ qa·qw with int32 accumulation (golden 8-bit datapath)."""
+    """Exact Σ qa·qw with int32 accumulation (golden 8-bit datapath).
+
+    The codes are 8-bit, so the dot takes uint8 operands: the same
+    exact integer result as an int32 x int32 dot, which a TPU has to
+    emulate at several times the compile time."""
 
     exact_int32 = True
     needs_library = False
@@ -174,16 +179,29 @@ class Int8Datapath(Datapath):
 
     def forward_q(self, qa, qw, consts):
         return jax.lax.dot_general(
-            qa, qw, (((1,), (0,)), ((), ())),
+            qa.astype(jnp.uint8), qw.astype(jnp.uint8),
+            (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32,
         )
 
 
-def _lut_gather_block(qa_blk: jax.Array, qw: jax.Array, flat_lut: jax.Array
-                      ) -> jax.Array:
-    """Σ_k LUT[qa, qw] for one row block. (mb,K) x (K,N) -> (mb,N) i32."""
-    idx = qa_blk[:, :, None] * 256 + qw[None, :, :]        # (mb,K,N)
-    prods = jnp.take(flat_lut, idx, axis=0)                 # (mb,K,N) i32
+def _lut_columns(qw: jax.Array, lut) -> jax.Array:
+    """The weight side of the lookup, resolved once per matmul:
+    (K*256, N) i32 whose row ``k*256 + v`` is ``LUT[v, qw[k, :]]``."""
+    k, n = qw.shape
+    lut = jnp.asarray(lut, dtype=jnp.int32).reshape(256, 256)
+    cols = jnp.take(lut, qw, axis=1)                        # (256,K,N)
+    return jnp.transpose(cols, (1, 0, 2)).reshape(k * 256, n)
+
+
+def _lut_gather_block(qa_blk: jax.Array, cols: jax.Array) -> jax.Array:
+    """Σ_k LUT[qa, qw] for one row block: (mb,K) codes -> (mb,N) i32.
+    Each (m, k) gathers one N-wide row of ``_lut_columns`` — N times
+    fewer gather indices than a gather per product, which is what a
+    TPU's gather throughput is bound by."""
+    k = qa_blk.shape[1]
+    rows = qa_blk + 256 * jnp.arange(k, dtype=jnp.int32)    # (mb,K)
+    prods = jnp.take(cols, rows, axis=0)                    # (mb,K,N) i32
     return jnp.sum(prods, axis=1, dtype=jnp.int32)
 
 
@@ -378,13 +396,12 @@ class LutDatapath(Datapath):
         if k > MAX_LUT_K:
             raise ValueError(
                 f"K={k} exceeds int32-safe LUT accumulation bound")
-        flat = jnp.asarray(consts["lut"], dtype=jnp.int32).reshape(-1)
+        cols = _lut_columns(qw, consts["lut"])
         mb = min(consts["block_m"], m)
         pad = (-m) % mb
         qa_p = jnp.pad(qa, ((0, pad), (0, 0)))
         blocks = qa_p.reshape(-1, mb, k)
-        out = jax.lax.map(
-            lambda blk: _lut_gather_block(blk, qw, flat), blocks)
+        out = jax.lax.map(lambda blk: _lut_gather_block(blk, cols), blocks)
         return out.reshape(-1, out.shape[-1])[:m]
 
 

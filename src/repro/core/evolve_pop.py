@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..kernels import platform
 from ..kernels.bitsim import bitsim_pop_pallas
 from ..kernels.ops import split_planes64
 from .cgp import (CgpParams, EvolvedCircuit, _Score, _score, mutate,
@@ -55,10 +56,6 @@ POP_PAD = 8
 _REDUCE_MAX_N_O = 24
 # values transfer as uint32, so the device engine caps at 32 outputs
 _DEVICE_MAX_N_O = 32
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pop_values(out32: jax.Array, n_o: int) -> jax.Array:
@@ -122,28 +119,26 @@ _device_values = jax.jit(
 def _sharded_reduce(mesh, axis, n_nodes, n_i, n_o, num, interpret):
     """shard_map'd ``_reduce_core``: candidate axis split across
     ``axis``, planes + exact values replicated on every device."""
-    from jax.experimental.shard_map import shard_map
     inner = functools.partial(_reduce_core, n_nodes=n_nodes, n_i=n_i,
                               n_o=n_o, num=num, interpret=interpret)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(axis, None),
                   P(axis, None), P(None, None), P(None)),
         out_specs=(P(axis), P(axis), P(axis, None)),
-        check_rep=False))
+        check_vma=False))
 
 
 @functools.lru_cache(maxsize=None)
 def _sharded_values(mesh, axis, n_nodes, n_i, n_o, interpret):
-    from jax.experimental.shard_map import shard_map
     inner = functools.partial(_values_core, n_nodes=n_nodes, n_i=n_i,
                               n_o=n_o, interpret=interpret)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(axis, None),
                   P(axis, None), P(None, None)),
         out_specs=P(axis, None),
-        check_rep=False))
+        check_vma=False))
 
 
 class PopEvaluator:
@@ -189,7 +184,7 @@ class PopEvaluator:
                     f"device engine caps at {_DEVICE_MAX_N_O} output "
                     f"bits (got {self.n_o}); use engine='numpy' for "
                     "wider circuits")
-            self.interpret = _interpret() if interpret is None \
+            self.interpret = platform.interpret() if interpret is None \
                 else interpret
             self.planes32 = jnp.asarray(split_planes64(self.planes64))
             numpad = self.planes32.shape[1] * 32

@@ -28,6 +28,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 W_BLOCK = 512
+#: candidates per SMEM netlist block of the population kernel (the
+#: sublane tile: SMEM blocks obey the same (8, 128) rule)
+POP_BLOCK = 8
 
 
 def _make_kernel(n_nodes: int, n_i: int, n_o: int):
@@ -86,11 +89,7 @@ def bitsim_pallas(funcs: jax.Array, in0: jax.Array, in1: jax.Array,
     out = pl.pallas_call(
         _make_kernel(n_nodes, n_i, n_o),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((n_nodes,), lambda i: (0,)),
-            pl.BlockSpec((n_nodes,), lambda i: (0,)),
-            pl.BlockSpec((n_nodes,), lambda i: (0,)),
-            pl.BlockSpec((n_o,), lambda i: (0,)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * 4 + [
             pl.BlockSpec((n_i, W_BLOCK), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((n_o, W_BLOCK), lambda i: (0, i)),
@@ -104,20 +103,22 @@ def bitsim_pallas(funcs: jax.Array, in0: jax.Array, in1: jax.Array,
 
 
 def _make_pop_kernel(n_nodes: int, n_i: int, n_o: int):
-    """Population variant of ``_make_kernel``: netlist refs carry a
-    leading singleton population-block dim selected by the grid."""
+    """Population variant of ``_make_kernel``: the netlist refs hold a
+    ``POP_BLOCK``-candidate SMEM block; the grid's candidate picks its
+    row."""
 
     def kernel(funcs_ref, in0_ref, in1_ref, outs_ref, planes_ref, o_ref,
                sig_ref):
+        q = jax.lax.rem(pl.program_id(0), POP_BLOCK)
         w = planes_ref.shape[1]
         sig_ref[0:n_i, :] = planes_ref[...]
         ones = jnp.full((1, w), 0xFFFFFFFF, dtype=jnp.uint32)
         zeros = jnp.zeros((1, w), dtype=jnp.uint32)
 
         def gate_body(j, _):
-            f = funcs_ref[0, j]
-            a = sig_ref[pl.ds(in0_ref[0, j], 1), :]
-            b = sig_ref[pl.ds(in1_ref[0, j], 1), :]
+            f = funcs_ref[q, j]
+            a = sig_ref[pl.ds(in0_ref[q, j], 1), :]
+            b = sig_ref[pl.ds(in1_ref[q, j], 1), :]
             r = jax.lax.switch(f, [
                 lambda a, b: a,            # identity
                 lambda a, b: ~a,           # not
@@ -136,7 +137,7 @@ def _make_pop_kernel(n_nodes: int, n_i: int, n_o: int):
         jax.lax.fori_loop(0, n_nodes, gate_body, 0)
 
         def out_body(o, _):
-            o_ref[0, pl.ds(o, 1), :] = sig_ref[pl.ds(outs_ref[0, o], 1), :]
+            o_ref[0, pl.ds(o, 1), :] = sig_ref[pl.ds(outs_ref[q, o], 1), :]
             return 0
 
         jax.lax.fori_loop(0, n_o, out_body, 0)
@@ -161,26 +162,32 @@ def bitsim_pop_pallas(funcs: jax.Array, in0: jax.Array, in1: jax.Array,
     output: padded nodes are appended past every referenced index.
     """
     p = funcs.shape[0]
+    pp = (-p) % POP_BLOCK
+    funcs, in0, in1, outs = (jnp.pad(x, ((0, pp), (0, 0)))
+                             for x in (funcs, in0, in1, outs))
     w = planes.shape[1]
     pw = (-w) % W_BLOCK
     planes_p = jnp.pad(planes, ((0, 0), (0, pw)))
     wp = planes_p.shape[1]
-    grid = (p, wp // W_BLOCK)
+    grid = (p + pp, wp // W_BLOCK)
+
+    def netlist_spec(width):
+        return pl.BlockSpec((POP_BLOCK, width),
+                            lambda q, i: (q // POP_BLOCK, 0),
+                            memory_space=pltpu.SMEM)
+
     out = pl.pallas_call(
         _make_pop_kernel(n_nodes, n_i, n_o),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, n_nodes), lambda q, i: (q, 0)),
-            pl.BlockSpec((1, n_nodes), lambda q, i: (q, 0)),
-            pl.BlockSpec((1, n_nodes), lambda q, i: (q, 0)),
-            pl.BlockSpec((1, n_o), lambda q, i: (q, 0)),
+        in_specs=[netlist_spec(n_nodes)] * 3 + [
+            netlist_spec(n_o),
             pl.BlockSpec((n_i, W_BLOCK), lambda q, i: (0, i)),
         ],
         out_specs=pl.BlockSpec((1, n_o, W_BLOCK), lambda q, i: (q, 0, i)),
-        out_shape=jax.ShapeDtypeStruct((p, n_o, wp), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((p + pp, n_o, wp), jnp.uint32),
         scratch_shapes=[
             pltpu.VMEM((n_i + n_nodes, W_BLOCK), jnp.uint32),
         ],
         interpret=interpret,
     )(funcs, in0, in1, outs, planes_p)
-    return out[:, :, :w]
+    return out[:p, :, :w]

@@ -19,7 +19,7 @@ VMEM budget per program (128/128/128 tiles, K_CHUNK=8):
 — the 4x cube term is the price of the four digit products; the banked
 variant pins exactly ONE tile-LUT slice per program (grid over the
 multiplier axis), so VMEM stays flat in ``n_mult`` exactly like the
-8-bit bank kernel (``lut_bank.py``).
+8-bit bank kernel (``approx_matmul.py``).
 
 The per-lane ``mask`` doubles as selector and truncation: wide lanes
 AND the reduced product with the netlist's 2W output bits (``0xFFFFFF``
@@ -38,7 +38,11 @@ from jax.experimental import pallas as pl
 
 from repro.approx.registry import composed_reduce
 
-from .approx_matmul import BK, BM, BN, K_CHUNK
+from .platform import no_mosaic
+
+BM, BN, BK = 128, 128, 128
+#: k-slice of each gathered (BM, K_CHUNK, BN) digit-product cube
+K_CHUNK = 8
 
 
 def _digit_cubes(a, w, lut, c):
@@ -122,6 +126,7 @@ def composed_matmul_pallas(qa: jax.Array, qw: jax.Array, lut: jax.Array,
     int32 tile LUT; mask: scalar uint32 2W-bit product mask (0 selects
     the narrow 8-bit path).  Returns (M,N) f32 ``lo + 65536*hi`` with
     exact int32 limb accumulation."""
+    no_mosaic("composed_matmul_pallas", interpret)
     m, k = qa.shape
     k2, n = qw.shape
     assert k == k2
@@ -167,6 +172,7 @@ def composed_matmul_bank_pallas(qa: jax.Array, qw: jax.Array,
     lane to ``composed_matmul_pallas`` — grid (n, M/BM, N/BN, K/BK)
     with one VMEM-pinned tile-LUT slice per program.
     """
+    no_mosaic("composed_matmul_bank_pallas", interpret)
     banked_a = qa.ndim == 3
     n_mult = luts.shape[0]
     m, k = qa.shape[-2:]

@@ -12,13 +12,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.approx.quant import calibrate, scalar_params
-from repro.approx.registry import (MAX_COMPOSED_K, Datapath, encode_reduce,
-                                   pack_lowrank, pack_lut,
+from repro.approx.registry import (MAX_COMPOSED_K, Datapath,
+                                   LowRankDatapath, encode_reduce, pack_lut,
                                    register_datapath)
 
 from .ops import (approx_matmul_lut, composed_matmul_lut,
-                  fused_composed_matmul_lut, fused_matmul_lut,
-                  lowrank_matmul)
+                  fused_composed_matmul_lut, fused_matmul_lut)
 
 
 @register_datapath("lut_pallas")
@@ -30,7 +29,7 @@ class LutPallasDatapath(Datapath):
 
     Bankable: under the batched engine's vmap, the ops' custom batching
     rules reroute the whole LUT bank to the banked kernels
-    (``lut_bank.py`` / ``composed_matmul.py``, grid over the
+    (``approx_matmul.py`` / ``composed_matmul.py``, grid over the
     multiplier axis) instead of batching the single-LUT kernel
     lane by lane."""
 
@@ -102,14 +101,8 @@ class LutFusedDatapath(Datapath):
 
 
 @register_datapath("lowrank_pallas")
-class LowRankPallasDatapath(Datapath):
-    """Rank-R factored emulation through the Pallas MXU kernel."""
-
-    spec_fields = ("multiplier", "rank")
-
-    def pack(self, spec, library) -> dict:
-        return pack_lowrank(spec, library)
-
-    def forward_q(self, qa, qw, consts):
-        return lowrank_matmul(qa, qw, jnp.asarray(consts["u"]),
-                              jnp.asarray(consts["v"]))
+class LowRankPallasDatapath(LowRankDatapath):
+    """The rank-R factored emulation has no Pallas kernel of its own:
+    Mosaic lowers no in-kernel table lookup, and with the lookups in
+    XLA what is left is R plain matmuls, which is the XLA ``lowrank``
+    datapath.  ``variant="pallas"`` therefore runs that datapath."""

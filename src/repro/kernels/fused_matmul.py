@@ -1,16 +1,21 @@
-"""Pallas TPU kernels: FUSED quantize→LUT-gather→accumulate datapath.
+"""Pallas TPU kernels: FUSED quantize→LUT→accumulate datapath.
 
 One ``pallas_call`` runs the integer half of the approximate-matmul
-datapath end to end (DESIGN.md §2.10): float operand tiles stream in,
-each tile is affine quantized in-register with pre-calibrated scalar
-params (SMEM), partial products are gathered from the VMEM-resident
-256x256 product LUT and accumulated exactly in int32 scratch alongside
-the zero-point row/col sums, and the final K-step applies the integer
-K-pad correction and emits the accumulator plus the row/col sums.
-Versus the two-step path (quantize → ``approx_matmul_lut`` →
-correct/dequant in XLA) this removes every intermediate int32
-code-tensor materialization and HBM round-trip — only the (M,N)
-accumulator and the tiny (M,)/(N,) sums leave the program.
+datapath (DESIGN.md §2.10): float activation tiles stream in, are
+affine quantized in-register with pre-calibrated scalar params (SMEM),
+and are contracted against the LUT exactly in int32.  Versus the
+two-step path (quantize → ``approx_matmul_lut`` → correct/dequant in
+XLA) no int32 activation-code tensor is materialized in HBM — only the
+(M,N) accumulator leaves the program.
+
+8-bit kernels use the one-hot MXU contraction of ``approx_matmul.py``.
+The weights are small next to the activations, so their codes are
+quantized in the caller (same op order as ``quant.quantize``) and
+resolve the LUT columns into the byte-plane tables; one extra table
+column holds the code value itself, so the same contraction also
+returns the activation row sums ``Σ_k qa`` the zero-point correction
+needs.  K-padding rows of the tables are zero, which masks padded
+activation codes out of both.
 
 The f32 zero-point correction + dequant deliberately stays in the
 jitted CALLER, written with the same expression shapes as
@@ -23,36 +28,23 @@ compiles to the same broadcast-protected HLO structure as the
 reference and stays bit-identical.  Everything UP TO the correction is
 integer arithmetic and therefore exact in any compilation context.
 
-Row blocking is shape-adaptive: ``bm = min(128, ceil8(M))`` instead of
-the fixed 128 of the code-domain kernels, so decode-like shapes (M of
-1..16 rows) stop paying for 128 gathered rows — the dominant term of
-the fused-vs-two-step speedup on small-M shapes (BENCH_kernels.json).
+The composed wide (12/16-bit) kernels still gather digit products from
+the VMEM-resident 256x256 tile LUT, which Mosaic does not lower: they
+run in interpret mode on the CPU only and raise on a TPU (ROADMAP.md
+S1).  Their banked variants double-buffer the LUT through VMEM scratch
+(the bank's LUT stack stays in HBM, ``memory_space=ANY``, and each
+bank's first tile prefetches the next bank's 256 KiB slice), with row
+blocking ``bm = min(128, ceil8(M))``:
 
-Banked variants add the ``LutBank`` lane axis as the outer grid
-dimension and DOUBLE-BUFFER the LUT through VMEM scratch: the bank's
-LUT stack stays in HBM (``memory_space=ANY``) and each bank's first
-tile starts an async DMA of the NEXT bank's 256 KiB slice into the
-alternate slot of a ``(2, 65536)`` scratch buffer while the current
-slice is consumed — the copy overlaps the whole bank's tile sweep.
-Operand tiles ride the pallas pipeline's own automatic double
-buffering via their BlockSpecs.  VMEM budget per program stays inside
-the repo's ~2.4 MiB envelope (DESIGN.md §2.6):
-
-  8-bit banked:    2*lut(512K) + x/w tiles(128K) + cube(512K)
-                   + acc/row/col(~68K) + out(64K)            ≈ 1.3 MiB
   composed banked: 2*lut(512K) + tiles(128K) + 4 cubes(1.0M)
                    + 2 acc limbs(132K) + outs(128K)          ≈ 1.9 MiB
 
-(the composed kernels drop K_CHUNK 8→4 to fit the 4 digit cubes next
-to the second LUT slot; chunking is int-associative so it cannot
-change results).
-
-The composed variants take the reduction tree as RUNTIME data — an
-``encode_reduce`` ``(kind, k)`` int pair in SMEM, applied via
-``composed_reduce_dyn`` — so one compiled program serves every adder
-family and mixed-reduce banks collapse to a single trace (the
-per-width/per-reduce program splits the trace audit in
-``launch/compile_cache.py`` measures).
+(K_CHUNK 4 fits the 4 digit cubes next to the second LUT slot;
+chunking is int-associative so it cannot change results).  They take
+the reduction tree as RUNTIME data — an ``encode_reduce`` ``(kind, k)``
+int pair in SMEM, applied via ``composed_reduce_dyn`` — so one compiled
+program serves every adder family and mixed-reduce banks collapse to a
+single trace.
 """
 from __future__ import annotations
 
@@ -66,7 +58,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.approx.registry import (MAX_COMPOSED_K, MAX_LUT_K,
                                    composed_reduce_dyn)
 
-from .approx_matmul import BK, BM, BN, K_CHUNK
+from .approx_matmul import lut_contract, lut_tables, quant_codes
+from .composed_matmul import BK, BM, BN
+from .platform import no_mosaic
 
 #: K-chunk of the composed fused kernels: 4 digit cubes per chunk must
 #: coexist with the second LUT scratch slot (module docstring budget).
@@ -77,13 +71,6 @@ def _row_block(m: int) -> int:
     """Shape-adaptive row block: full 128 rows for large M, the 8-row
     f32 tile floor for decode-like shapes (no 128-row gather padding)."""
     return max(8, min(BM, ((m + 7) // 8) * 8))
-
-
-def _quant_tile(v, scale, zp, qmax):
-    """In-kernel ``repro.approx.quant.quantize`` on one f32 tile —
-    identical op/dtype order (round, +int32 zp in f32, clip, cast)."""
-    q = jnp.round(v / scale) + zp
-    return jnp.clip(q, 0, qmax).astype(jnp.int32)
 
 
 def _k_masked(qa, qw, k_step, k, pk):
@@ -153,110 +140,6 @@ def _lut_slot(lut_hbm, buf_ref, sem_ref, b, first_tile, n_mult):
 
 
 # ----------------------------------------------------------------------
-# 8-bit fused kernels
-# ----------------------------------------------------------------------
-def _fused_kernel(x_ref, w_ref, lut_ref, fp_ref, ip_ref,
-                  o_ref, row_o, col_o, acc_ref, row_ref, col_ref,
-                  *, k, pk, nsteps, bm):
-    j, k_step = pl.program_id(1), pl.program_id(2)
-
-    @pl.when(k_step == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        row_ref[...] = jnp.zeros_like(row_ref)
-        col_ref[...] = jnp.zeros_like(col_ref)
-
-    sa, sw, qmax = fp_ref[0], fp_ref[1], fp_ref[2]
-    za, zw = ip_ref[0], ip_ref[1]
-    qa = _quant_tile(x_ref[...], sa, za, qmax)       # (bm, BK)
-    qw = _quant_tile(w_ref[...], sw, zw, qmax)       # (BK, BN)
-    qa, qw = _k_masked(qa, qw, k_step, k, pk)
-    row_ref[...] += jnp.sum(qa, axis=1, dtype=jnp.int32)[:, None]
-    col_ref[...] += jnp.sum(qw, axis=0, dtype=jnp.int32)[None, :]
-    lut = lut_ref[...]
-
-    def body(c, acc):
-        a_c = jax.lax.dynamic_slice(qa, (0, c * K_CHUNK), (bm, K_CHUNK))
-        w_c = jax.lax.dynamic_slice(qw, (c * K_CHUNK, 0),
-                                    (K_CHUNK, qw.shape[1]))
-        idx = a_c[:, :, None] * 256 + w_c[None, :, :]    # (bm,KC,BN)
-        prods = jnp.take(lut, idx, axis=0)                # VPU gather
-        return acc + jnp.sum(prods, axis=1, dtype=jnp.int32)
-
-    acc = jax.lax.fori_loop(0, BK // K_CHUNK, body,
-                            jnp.zeros((bm, qw.shape[1]), jnp.int32))
-    acc_ref[...] += acc
-
-    @pl.when(k_step == nsteps - 1)
-    def _fin():
-        a = acc_ref[...]
-        if pk:
-            a = a - jnp.int32(pk) * lut[0]
-        o_ref[...] = a
-
-    @pl.when((k_step == nsteps - 1) & (j == 0))
-    def _row():
-        row_o[...] = row_ref[...]
-
-    @pl.when((k_step == nsteps - 1) & (pl.program_id(0) == 0))
-    def _col():
-        col_o[...] = col_ref[...]
-
-
-def _fused_bank_kernel(x_ref, w_ref, lut_hbm, fp_ref, ip_ref,
-                       o_ref, row_o, col_o, acc_ref, row_ref, col_ref,
-                       buf_ref, sem_ref,
-                       *, k, pk, nsteps, bm, n_mult, banked_a):
-    b = pl.program_id(0)
-    i, j = pl.program_id(1), pl.program_id(2)
-    k_step = pl.program_id(3)
-    first_tile = (i == 0) & (j == 0) & (k_step == 0)
-    lut = _lut_slot(lut_hbm, buf_ref, sem_ref, b, first_tile, n_mult)
-
-    @pl.when(k_step == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        row_ref[...] = jnp.zeros_like(row_ref)
-        col_ref[...] = jnp.zeros_like(col_ref)
-
-    sa, sw, qmax = fp_ref[b, 0], fp_ref[b, 1], fp_ref[b, 2]
-    za, zw = ip_ref[b, 0], ip_ref[b, 1]
-    x = x_ref[...].reshape(-1, x_ref.shape[-1]) if banked_a else x_ref[...]
-    qa = _quant_tile(x, sa, za, qmax)                # (bm, BK)
-    qw = _quant_tile(w_ref[...], sw, zw, qmax)       # (BK, BN)
-    qa, qw = _k_masked(qa, qw, k_step, k, pk)
-    row_ref[...] += jnp.sum(qa, axis=1, dtype=jnp.int32)[:, None]
-    col_ref[...] += jnp.sum(qw, axis=0, dtype=jnp.int32)[None, :]
-
-    def body(c, acc):
-        a_c = jax.lax.dynamic_slice(qa, (0, c * K_CHUNK), (bm, K_CHUNK))
-        w_c = jax.lax.dynamic_slice(qw, (c * K_CHUNK, 0),
-                                    (K_CHUNK, qw.shape[1]))
-        idx = a_c[:, :, None] * 256 + w_c[None, :, :]
-        return acc + jnp.sum(jnp.take(lut, idx, axis=0), axis=1,
-                             dtype=jnp.int32)
-
-    acc = jax.lax.fori_loop(0, BK // K_CHUNK, body,
-                            jnp.zeros((bm, qw.shape[1]), jnp.int32))
-    acc_ref[...] += acc
-
-    @pl.when(k_step == nsteps - 1)
-    def _fin():
-        a = acc_ref[...]
-        if pk:
-            a = a - jnp.int32(pk) * lut[0]
-        o_ref[...] = a[None]
-
-    @pl.when((k_step == nsteps - 1) & (j == 0))
-    def _row():
-        row_o[...] = row_ref[...][None]
-
-    @pl.when((k_step == nsteps - 1) & (i == 0))
-    def _col():
-        col_o[...] = col_ref[...][None]
-
-
-# ----------------------------------------------------------------------
 # Composed wide (12/16-bit) fused kernels — runtime reduce (SMEM rcode)
 # ----------------------------------------------------------------------
 def _digit_body(qa, qw, lut, mask, kind, kd, bm, bn):
@@ -319,8 +202,8 @@ def _fused_composed_kernel(x_ref, w_ref, lut_ref, mask_ref, rc_ref,
     za, zw = ip_ref[0], ip_ref[1]
     mask = mask_ref[0]
     kind, kd = rc_ref[0], rc_ref[1]
-    qa = _quant_tile(x_ref[...], sa, za, qmax)
-    qw = _quant_tile(w_ref[...], sw, zw, qmax)
+    qa = quant_codes(x_ref[...], sa, za, qmax)
+    qw = quant_codes(w_ref[...], sw, zw, qmax)
     qa, qw = _k_masked(qa, qw, k_step, k, pk)
     row_ref[...] += jnp.sum(qa, axis=1, dtype=jnp.int32)[:, None]
     col_ref[...] += jnp.sum(qw, axis=0, dtype=jnp.int32)[None, :]
@@ -371,8 +254,8 @@ def _fused_composed_bank_kernel(x_ref, w_ref, lut_hbm, mask_ref, rc_ref,
     mask = mask_ref[b]
     kind, kd = rc_ref[b, 0], rc_ref[b, 1]
     x = x_ref[...].reshape(-1, x_ref.shape[-1]) if banked_a else x_ref[...]
-    qa = _quant_tile(x, sa, za, qmax)
-    qw = _quant_tile(w_ref[...], sw, zw, qmax)
+    qa = quant_codes(x, sa, za, qmax)
+    qw = quant_codes(w_ref[...], sw, zw, qmax)
     qa, qw = _k_masked(qa, qw, k_step, k, pk)
     row_ref[...] += jnp.sum(qa, axis=1, dtype=jnp.int32)[:, None]
     col_ref[...] += jnp.sum(qw, axis=0, dtype=jnp.int32)[None, :]
@@ -442,6 +325,28 @@ def _bank_dequant(s, row, col, za, zw, sa, sw, k: int):
     return acc * (saf * swf)[:, None, None]
 
 
+def _fused_core(x, w, luts, fp, ip, interpret: bool):
+    """Integer half of the banked fused datapath: per-lane weight codes
+    (quantized here, in XLA) resolve the LUT columns into the one-hot
+    tables, whose extra column carries the activation row sums; the
+    kernel quantizes ``x`` and contracts.  Returns the (n,M,N) int32
+    accumulator, the (n,M) row sums and the (n,N) weight column sums."""
+    m, k = x.shape[-2:]
+    n = w.shape[1]
+    _check_k(k, MAX_LUT_K, "LUT")
+    qw = jax.vmap(lambda s, z, q: quant_codes(w, s, z, q))(
+        fp[:, 1], ip[:, 1], fp[:, 2])                        # (n,K,N)
+    t_lo, t_hi = jax.vmap(
+        lambda q, lut: lut_tables(q, lut, row_sums=True))(qw, luts)
+    acc = lut_contract(x, t_lo, t_hi, (fp, ip), interpret=interpret)
+    # the same fence as ``backend._quantized_matmul``'s around the
+    # integer result: the f32 epilogue then compiles alike for every
+    # datapath
+    return jax.lax.optimization_barrier(
+        (acc[:, :m, :n], acc[:, :m, n],
+         jnp.sum(qw, axis=1, dtype=jnp.int32)))
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_matmul_pallas(x, w, lut, sa, za, sw, zw, qmax,
                         interpret: bool = False) -> jax.Array:
@@ -449,38 +354,11 @@ def fused_matmul_pallas(x, w, lut, sa, za, sw, zw, qmax,
     i32, scalars from ``quant.scalar_params``.  Returns (M,N) f32 —
     bit-identical to quantize → ``approx_matmul_lut`` → correct/dequant.
     """
-    m, k = x.shape
-    _, n = w.shape
-    _check_k(k, MAX_LUT_K, "LUT")
-    bm = _row_block(m)
-    x_p, w_p, pk = _pad_operands(x, w, bm, banked_a=False)
     fp, ip = _pack_scalars(sa, sw, qmax, za, zw, stacked=False)
-    nsteps = x_p.shape[1] // BK
-    grid = (x_p.shape[0] // bm, w_p.shape[1] // BN, nsteps)
-    mp, np_ = x_p.shape[0], w_p.shape[1]
-    acc, row, col = pl.pallas_call(
-        functools.partial(_fused_kernel, k=k, pk=pk, nsteps=nsteps, bm=bm),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, BK), lambda i, j, s: (i, s)),
-            pl.BlockSpec((BK, BN), lambda i, j, s: (s, j)),
-            pl.BlockSpec((65536,), lambda i, j, s: (0,)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_specs=[pl.BlockSpec((bm, BN), lambda i, j, s: (i, j)),
-                   pl.BlockSpec((bm, 1), lambda i, j, s: (i, 0)),
-                   pl.BlockSpec((1, BN), lambda i, j, s: (0, j))],
-        out_shape=[jax.ShapeDtypeStruct((mp, np_), jnp.int32),
-                   jax.ShapeDtypeStruct((mp, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((1, np_), jnp.int32)],
-        scratch_shapes=[pltpu.VMEM((bm, BN), jnp.int32),
-                        pltpu.VMEM((bm, 1), jnp.int32),
-                        pltpu.VMEM((1, BN), jnp.int32)],
-        interpret=interpret,
-    )(x_p, w_p, lut.reshape(-1), fp, ip)
-    s = acc[:m, :n].astype(jnp.float32)
-    return _dequant(s, row[:m, 0], col[0, :n], za, zw, sa, sw, k)
+    acc, row, col = _fused_core(x, w, lut[None], fp[None], ip[None],
+                                interpret)
+    return _dequant(acc[0].astype(jnp.float32), row[0], col[0],
+                    za, zw, sa, sw, x.shape[1])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -488,51 +366,11 @@ def fused_matmul_bank_pallas(x, w, luts, sa, za, sw, zw, qmax,
                              interpret: bool = False) -> jax.Array:
     """Banked fused 8-bit datapath: x (M,K) shared or (n,M,K) banked
     f32; luts (n,256,256); scalars (n,) per lane.  Returns (n,M,N) f32,
-    bit-identical per lane to ``fused_matmul_pallas`` — LUT slices are
-    DMA double-buffered from HBM (module docstring)."""
-    banked_a = x.ndim == 3
-    n_mult = luts.shape[0]
-    m, k = x.shape[-2:]
-    _, n = w.shape
-    _check_k(k, MAX_LUT_K, "LUT")
-    bm = _row_block(m)
-    x_p, w_p, pk = _pad_operands(x, w, bm, banked_a)
+    bit-identical per lane to ``fused_matmul_pallas``."""
     fp, ip = _pack_scalars(sa, sw, qmax, za, zw, stacked=True)
-    nsteps = x_p.shape[-1] // BK
-    grid = (n_mult, x_p.shape[-2] // bm, w_p.shape[1] // BN, nsteps)
-    if banked_a:
-        x_spec = pl.BlockSpec((1, bm, BK), lambda b, i, j, s: (b, i, s))
-    else:
-        x_spec = pl.BlockSpec((bm, BK), lambda b, i, j, s: (i, s))
-    mp, np_ = x_p.shape[-2], w_p.shape[1]
-    acc, row, col = pl.pallas_call(
-        functools.partial(_fused_bank_kernel, k=k, pk=pk, nsteps=nsteps,
-                          bm=bm, n_mult=n_mult, banked_a=banked_a),
-        grid=grid,
-        in_specs=[
-            x_spec,
-            pl.BlockSpec((BK, BN), lambda b, i, j, s: (s, j)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bm, BN), lambda b, i, j, s: (b, i, j)),
-            pl.BlockSpec((1, bm, 1), lambda b, i, j, s: (b, i, 0)),
-            pl.BlockSpec((1, 1, BN), lambda b, i, j, s: (b, 0, j))],
-        out_shape=[jax.ShapeDtypeStruct((n_mult, mp, np_), jnp.int32),
-                   jax.ShapeDtypeStruct((n_mult, mp, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((n_mult, 1, np_), jnp.int32)],
-        scratch_shapes=[pltpu.VMEM((bm, BN), jnp.int32),
-                        pltpu.VMEM((bm, 1), jnp.int32),
-                        pltpu.VMEM((1, BN), jnp.int32),
-                        pltpu.VMEM((2, 65536), jnp.int32),
-                        pltpu.SemaphoreType.DMA((2,))],
-        interpret=interpret,
-    )(x_p, w_p, luts.reshape(n_mult, -1), fp, ip)
-    s = acc[:, :m, :n].astype(jnp.float32)
-    return _bank_dequant(s, row[:, :m, 0], col[:, 0, :n],
-                         za, zw, sa, sw, k)
+    acc, row, col = _fused_core(x, w, luts, fp, ip, interpret)
+    return _bank_dequant(acc.astype(jnp.float32), row, col,
+                         za, zw, sa, sw, x.shape[-1])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -543,6 +381,7 @@ def fused_composed_matmul_pallas(x, w, lut, mask, rcode, sa, za, sw, zw,
     products through the 256x256 tile LUT, runtime ``rcode`` reduce
     tree (``encode_reduce``), int32 limb accumulation, f32 correction.
     mask: scalar uint32 (0 = narrow lane); rcode: (2,) int32."""
+    no_mosaic("fused_composed_matmul_pallas", interpret)
     m, k = x.shape
     _, n = w.shape
     _check_k(k, MAX_COMPOSED_K, "composed limb")
@@ -595,6 +434,7 @@ def fused_composed_matmul_bank_pallas(x, w, luts, masks, rcodes, sa, za,
     reduce codes (n,2) int32 ride SMEM next to the per-lane quant
     scalars, so ONE program evaluates a mixed-width, mixed-reduce bank
     (n,M,N) — LUT slices DMA double-buffered from HBM."""
+    no_mosaic("fused_composed_matmul_bank_pallas", interpret)
     banked_a = x.ndim == 3
     n_mult = luts.shape[0]
     m, k = x.shape[-2:]
@@ -619,7 +459,7 @@ def fused_composed_matmul_bank_pallas(x, w, luts, masks, rcodes, sa, za,
         in_specs=[
             x_spec,
             pl.BlockSpec((BK, BN), lambda b, i, j, s: (s, j)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),

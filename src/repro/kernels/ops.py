@@ -1,8 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode —
-the kernel body runs verbatim, which is how they are validated against
-the ``ref.py`` oracles.  On a TPU backend the same calls lower to Mosaic.
+On the CPU the kernels execute in ``interpret=True`` mode — the kernel
+body runs verbatim, which is how they are validated against the
+``ref.py`` oracles.  On a TPU the same calls lower to Mosaic
+(``platform.interpret``); kernels without a Mosaic lowering raise there.
 """
 from __future__ import annotations
 
@@ -13,19 +14,15 @@ import jax.custom_batching
 import jax.numpy as jnp
 import numpy as np
 
-from .approx_matmul import approx_matmul_lut_pallas
+from .approx_matmul import (approx_matmul_lut_bank_pallas,
+                            approx_matmul_lut_pallas)
 from .composed_matmul import (composed_matmul_bank_pallas,
                               composed_matmul_pallas)
 from .fused_matmul import (fused_composed_matmul_bank_pallas,
                            fused_composed_matmul_pallas,
                            fused_matmul_bank_pallas, fused_matmul_pallas)
-from .lut_bank import approx_matmul_lut_bank_pallas
-from .lowrank_matmul import lowrank_matmul_pallas
 from .bitsim import bitsim_pallas, bitsim_pop_pallas
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from . import platform
 
 
 @jax.custom_batching.custom_vmap
@@ -39,7 +36,8 @@ def approx_matmul_lut(qa: jax.Array, qw: jax.Array, lut: jax.Array
     batched resilience engine turns an n-multiplier sweep into one
     launch (DESIGN.md §2.4).
     """
-    return approx_matmul_lut_pallas(qa, qw, lut, interpret=_interpret())
+    return approx_matmul_lut_pallas(qa, qw, lut,
+                                    interpret=platform.interpret())
 
 
 @approx_matmul_lut.def_vmap
@@ -50,7 +48,7 @@ def _approx_matmul_lut_vmap(axis_size, in_batched, qa, qw, lut):
         # a LUT bank: keep pallas_call's native parallel batching rule.
         out = jax.vmap(
             lambda a, w, l: approx_matmul_lut_pallas(
-                a, w, l, interpret=_interpret()),
+                a, w, l, interpret=platform.interpret()),
             in_axes=(0 if qa_b else None, 0, 0 if lut_b else None),
         )(qa, qw, lut)
         return out, True
@@ -65,7 +63,7 @@ def approx_matmul_lut_bank(qa: jax.Array, qw: jax.Array, luts: jax.Array
     qa: (M,K) shared or (n,M,K) banked codes; luts: (n,256,256)
     -> (n,M,N) i32, bit-identical per bank to ``approx_matmul_lut``."""
     return approx_matmul_lut_bank_pallas(qa, qw, luts,
-                                         interpret=_interpret())
+                                         interpret=platform.interpret())
 
 
 @functools.lru_cache(maxsize=None)
@@ -80,7 +78,7 @@ def _composed_op(reduce: tuple):
     @jax.custom_batching.custom_vmap
     def op(qa, qw, lut, mask):
         return composed_matmul_pallas(qa, qw, lut, mask, reduce=reduce,
-                                      interpret=_interpret())
+                                      interpret=platform.interpret())
 
     @op.def_vmap
     def _op_vmap(axis_size, in_batched, qa, qw, lut, mask):
@@ -89,7 +87,8 @@ def _composed_op(reduce: tuple):
             # batched weights (experts) are not a LUT bank: native rule
             out = jax.vmap(
                 lambda a, w, l, mk: composed_matmul_pallas(
-                    a, w, l, mk, reduce=reduce, interpret=_interpret()),
+                    a, w, l, mk, reduce=reduce,
+                    interpret=platform.interpret()),
                 in_axes=(0 if qa_b else None, 0, 0 if lut_b else None,
                          0 if mask_b else None),
             )(qa, qw, lut, mask)
@@ -100,7 +99,7 @@ def _composed_op(reduce: tuple):
                  else jnp.broadcast_to(jnp.asarray(mask), (axis_size,)))
         out = composed_matmul_bank_pallas(qa, qw, luts, masks,
                                           reduce=reduce,
-                                          interpret=_interpret())
+                                          interpret=platform.interpret())
         return out, True
 
     return op
@@ -136,7 +135,7 @@ def fused_matmul_lut(x: jax.Array, w: jax.Array, lut: jax.Array,
     over (lut, scalars) to the banked fused kernel so bank sweeps stay
     one launch; batched weights keep the native rule."""
     return fused_matmul_pallas(x, w, lut, sa, za, sw, zw, qmax,
-                               interpret=_interpret())
+                               interpret=platform.interpret())
 
 
 @fused_matmul_lut.def_vmap
@@ -146,7 +145,7 @@ def _fused_matmul_lut_vmap(axis_size, in_batched, x, w, lut,
     if w_b:
         # batched weights (experts) are not a LUT bank: native rule
         out = jax.vmap(
-            lambda *a: fused_matmul_pallas(*a, interpret=_interpret()),
+            lambda *a: fused_matmul_pallas(*a, interpret=platform.interpret()),
             in_axes=tuple(0 if b else None for b in in_batched),
         )(x, w, lut, sa, za, sw, zw, qmax)
         return out, True
@@ -166,7 +165,7 @@ def fused_matmul_lut_bank(x: jax.Array, w: jax.Array, luts: jax.Array,
     luts: (n,256,256) -> (n,M,N) f32, per lane bit-identical to
     ``fused_matmul_lut``.  LUT slices are DMA double-buffered."""
     return fused_matmul_bank_pallas(x, w, luts, sa, za, sw, zw, qmax,
-                                    interpret=_interpret())
+                                    interpret=platform.interpret())
 
 
 @jax.custom_batching.custom_vmap
@@ -181,7 +180,7 @@ def fused_composed_matmul_lut(x: jax.Array, w: jax.Array,
     per-reduce ``composed_matmul_lut`` specializations."""
     return fused_composed_matmul_pallas(x, w, lut, mask, rcode,
                                         sa, za, sw, zw, qmax,
-                                        interpret=_interpret())
+                                        interpret=platform.interpret())
 
 
 @fused_composed_matmul_lut.def_vmap
@@ -191,7 +190,7 @@ def _fused_composed_matmul_lut_vmap(axis_size, in_batched, x, w, lut,
     if w_b:
         out = jax.vmap(
             lambda *a: fused_composed_matmul_pallas(
-                *a, interpret=_interpret()),
+                *a, interpret=platform.interpret()),
             in_axes=tuple(0 if b else None for b in in_batched),
         )(x, w, lut, mask, rcode, sa, za, sw, zw, qmax)
         return out, True
@@ -211,13 +210,7 @@ def fused_composed_matmul_lut_bank(x: jax.Array, w: jax.Array,
     mixed-reduce banks evaluate in a single launch."""
     return fused_composed_matmul_bank_pallas(
         x, w, luts, masks, rcodes, sa, za, sw, zw, qmax,
-        interpret=_interpret())
-
-
-def lowrank_matmul(qa: jax.Array, qw: jax.Array, u: jax.Array, v: jax.Array
-                   ) -> jax.Array:
-    """Rank-R factored approximate matmul. (M,K)x(K,N)->(M,N) f32."""
-    return lowrank_matmul_pallas(qa, qw, u, v, interpret=_interpret())
+        interpret=platform.interpret())
 
 
 def bitsim(netlist, planes64: np.ndarray) -> np.ndarray:
@@ -229,7 +222,7 @@ def bitsim(netlist, planes64: np.ndarray) -> np.ndarray:
         jnp.asarray(netlist.in1), jnp.asarray(netlist.outputs),
         jnp.asarray(split_planes64(planes64)),
         n_nodes=netlist.n_nodes, n_i=netlist.n_i, n_o=netlist.n_o,
-        interpret=_interpret(),
+        interpret=platform.interpret(),
     ))
     return join_planes32(out32)
 
@@ -267,6 +260,6 @@ def bitsim_pop(netlists, planes64: np.ndarray) -> np.ndarray:
         jnp.asarray(funcs), jnp.asarray(in0), jnp.asarray(in1),
         jnp.asarray(outs), jnp.asarray(split_planes64(planes64)),
         n_nodes=funcs.shape[1], n_i=first.n_i, n_o=first.n_o,
-        interpret=_interpret(),
+        interpret=platform.interpret(),
     ))
     return join_planes32(out32)

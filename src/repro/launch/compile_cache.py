@@ -28,6 +28,7 @@ import os
 from dataclasses import dataclass, field
 
 import jax
+from jax.experimental.compilation_cache import compilation_cache
 
 # Events published by jax/_src/compiler.py and jax/_src/compilation_cache.py.
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -76,11 +77,7 @@ def enable_compile_cache(cache_dir: str | None = None) -> str:
     # the process (compilation_cache.is_cache_used); enabling the cache
     # after any jit call would otherwise be a silent no-op, so drop
     # that memo and let the next compile re-check the config.
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except (ImportError, AttributeError):  # pragma: no cover
-        pass
+    compilation_cache.reset_cache()
     return d
 
 

@@ -81,9 +81,28 @@ def init_params(key, cfg: ResNetConfig) -> dict:
     return params
 
 
+def _ordered_mean(x, axes):
+    """Mean over ``axes`` (kept as size 1) summed as a fixed binary tree
+    of elementwise adds.  An f32 reduce may add in any order, and on a
+    TPU XLA takes the order from the operand's layout, which differs
+    between programs (a banked sweep and the sequential one, or two
+    datapaths); the tree fixes the order, so every program computes the
+    same bits — which the bit-identical sweeps rely on."""
+    keep = tuple(d for d in range(x.ndim) if d not in axes)
+    n = int(np.prod([x.shape[a] for a in axes]))
+    y = jnp.transpose(x, tuple(axes) + keep)
+    y = y.reshape((n,) + tuple(x.shape[d] for d in keep))
+    y = jnp.pad(y, [(0, (1 << (n - 1).bit_length()) - n)]
+                + [(0, 0)] * len(keep))
+    while y.shape[0] > 1:
+        y = y[:y.shape[0] // 2] + y[y.shape[0] // 2:]
+    shape = [1 if d in axes else x.shape[d] for d in range(x.ndim)]
+    return (y[0] / n).reshape(shape)
+
+
 def _bn(x, g, b, eps):
-    mu = jnp.mean(x, axis=(0, 1, 2), keepdims=True)
-    var = jnp.var(x, axis=(0, 1, 2), keepdims=True)
+    mu = _ordered_mean(x, (0, 1, 2))
+    var = _ordered_mean(jnp.square(x - mu), (0, 1, 2))
     return (x - mu) * jax.lax.rsqrt(var + eps) * g + b
 
 
@@ -115,7 +134,7 @@ def forward(params, images, cfg: ResNetConfig,
                 sc = x
             x = jax.nn.relu(y + sc)
             cin = width
-    x = jnp.mean(x, axis=(1, 2))
+    x = _ordered_mean(x, (1, 2)).reshape(x.shape[0], x.shape[3])
     return policy.matmul("head", x, params["head"]["w"]) + params["head"]["b"]
 
 

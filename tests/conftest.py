@@ -9,15 +9,3 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 _root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(_root, "src"))
 sys.path.insert(0, _root)  # for `import benchmarks` in integration tests
-
-# Property tests must never hard-error collection when hypothesis is
-# absent (requirements-dev.txt installs the real one for CI); fall back
-# to a small deterministic sampler with the same decorator API.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from _hypothesis_fallback import build_module
-    mod = build_module()
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = mod.strategies

@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-pytest.importorskip("hypothesis")  # property tests; see requirements-dev.txt
 from hypothesis import given, settings, strategies as st
 
 from repro.approx.layers import EXACT_POLICY

@@ -80,7 +80,8 @@ def test_policy_override_precedence():
     assert pol.backend_for("layer2.conv") is be_a
 
 
-@pytest.mark.parametrize("stride,pad", [(1, "SAME"), (2, "SAME")])
+@pytest.mark.parametrize("stride,pad", [(1, "SAME"), (2, "SAME"),
+                                        (1, "VALID"), (2, "VALID")])
 def test_conv2d_matches_lax_conv(stride, pad):
     x = jnp.asarray(RNG.normal(size=(2, 16, 16, 3)), jnp.float32)
     w = jnp.asarray(RNG.normal(size=(3, 3, 3, 8)), jnp.float32)

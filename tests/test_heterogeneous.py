@@ -381,41 +381,47 @@ def min_primary_workload():
 
 def test_explore_heterogeneous_exact_predictor_pin(lib,
                                                    min_primary_workload):
-    """Same seed + predictor="exact" reproduces today's shortlist
-    bit-identically: baseline, verified points (order, accuracy,
-    power), selection, and the JSON surface (no surrogate key)."""
-    res = explore_heterogeneous(
-        min_primary_workload, dict(COUNTS), lib, multipliers=MULTS,
-        quality_bound=30.0, top_k=6)
-    assert res.baseline_accuracy == 0.12060075998306274
-    expected = [
-        ({"lin_a": "mul8u_trunc2", "lin_b": "mul8u_trunc2"},
-         8.694466590881348, 0.023479520066197766),
-        ({"lin_a": "mul8u_trunc4", "lin_b": "mul8u_trunc2"},
-         8.662344932556152, 0.06710281340504759),
-        ({"lin_a": "mul8u_trunc2", "lin_b": "mul8u_trunc4"},
-         8.694466590881348, 0.15434940008274725),
-        ({"lin_a": "mul8u_trunc4", "lin_b": "mul8u_trunc4"},
-         8.666534423828125, 0.1979726934215971),
-        ({"lin_a": "mul8u_exact", "lin_b": "mul8u_trunc2"},
-         39.7521858215332, 0.2676096400496483),
-        ({"lin_a": "mul8u_exact", "lin_b": "mul8u_trunc4"},
-         11.416650772094727, 0.3984795200661978),
-    ]
-    assert len(res.heterogeneous) == len(expected)
-    for p, (assign, acc, pw) in zip(res.heterogeneous, expected):
-        assert dict(p.assignment) == assign
-        assert p.accuracy == acc
-        assert p.network_rel_power == pw
+    """predictor="exact" agrees with an in-process sequential
+    computation: the baseline, every stage-1 per-layer row, each
+    verified shortlist point (accuracy and power, in power order) and
+    the selection — and the JSON surface (no surrogate key)."""
+    wl = min_primary_workload
+    bound = 30.0
+    res = explore_heterogeneous(wl, dict(COUNTS), lib, multipliers=MULTS,
+                                quality_bound=bound, top_k=6)
+    golden = BackendSpec.golden().materialize()
+
+    def measure(overrides):
+        policy = ApproxPolicy(default=golden, overrides=[
+            (layer, BackendSpec(mode="lut", multiplier=m).materialize(lib))
+            for layer, m in overrides])
+        return wl.measure(policy)[wl.primary]
+
+    baseline = measure([])
+    assert res.baseline_accuracy == baseline
+    # per-layer stage-1 rows are the exact sweep, one layer at a time
+    assert sorted((p.multiplier, p.layer) for p in res.per_layer) == \
+        sorted((m, layer) for m in MULTS for layer in LAYERS)
+    for p in res.per_layer:
+        assert p.accuracy == measure([(p.layer, p.multiplier)])
+    # the verified shortlist: top_k compositions in power order, each
+    # measured exactly with count-weighted power
+    rel_power = {m: lib.entry(m).rel_power for m in MULTS}
+    assert len(res.heterogeneous) == 6
+    assert len({p.assignment for p in res.heterogeneous}) == 6
+    for p in res.heterogeneous:
+        assert p.accuracy == measure(p.assignment)
+        assert p.network_rel_power == network_power_for_assignment(
+            COUNTS, dict(p.assignment), rel_power)
+    powers = [p.network_rel_power for p in res.heterogeneous]
+    assert powers == sorted(powers)
+    # selection: the lowest-power verified point within the bound (the
+    # primary, logit MAE, is minimized)
+    feasible = [p for p in res.heterogeneous
+                if p.accuracy - baseline <= bound]
     assert res.selected is not None
-    assert res.selected.accuracy == 8.694466590881348
-    # per-layer stage-1 rows are the exact sweep, pinned
-    by_cell = {(p.multiplier, p.layer): p.accuracy for p in res.per_layer}
-    assert by_cell[("mul8u_exact", "lin_a")] == 0.12060081958770752
-    assert by_cell[("mul8u_trunc4", "lin_a")] == 8.670662879943848
-    assert by_cell[("mul8u_trunc4", "lin_b")] == 11.416650772094727
-    assert by_cell[("mul8u_trunc2", "lin_a")] == 8.694466590881348
-    assert by_cell[("mul8u_trunc2", "lin_b")] == 39.7521858215332
+    assert res.selected.assignment == min(
+        feasible, key=lambda p: p.network_rel_power).assignment
     # JSON surface unchanged: no surrogate key on the exact path, and
     # a faithful round-trip
     d = res.to_json_dict()

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.approx.registry import get_datapath
 from repro.core import families, seeds
 from repro.core.luts import decompose_lut, exact_mul_lut, lut_from_netlist
 from repro.core.netlist import exhaustive_inputs, random_input_planes
@@ -94,6 +95,13 @@ def test_lut_kernel_vmap_batched_weights():
     np.testing.assert_array_equal(np.asarray(got), want)
 
 
+def _lowrank(qa, qw, u, v):
+    """The low-rank matmul as ``variant="pallas"`` runs it: the XLA
+    ``lowrank`` datapath (there is no low-rank Pallas kernel)."""
+    return get_datapath("lowrank_pallas").forward_q(qa, qw,
+                                                    {"u": u, "v": v})
+
+
 @settings(max_examples=6, deadline=None)
 @given(st.integers(1, 130), st.integers(1, 140), st.integers(1, 130),
        st.integers(1, 6))
@@ -101,9 +109,9 @@ def test_lowrank_kernel_matches_ref(m, k, n, r):
     qa, qw = _codes(m, k, n)
     u = jnp.asarray(RNG.normal(size=(r, 256)).astype(np.float32))
     v = jnp.asarray(RNG.normal(size=(r, 256)).astype(np.float32))
-    got = ops.lowrank_matmul(qa, qw, u, v)
+    got = _lowrank(qa, qw, u, v)
     want = ref.lowrank_matmul_ref(qa, qw, u, v)
-    # f32 reduction-order noise grows with K (blocked vs flat accumulate)
+    # f32 reduction-order noise grows with K (einsum vs flat accumulate)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-3, atol=1e-2)
 
@@ -113,7 +121,7 @@ def test_lowrank_kernel_emulates_exact_multiplier():
     lut = exact_mul_lut(8)
     fac = decompose_lut(lut, 1)
     qa, qw = _codes(32, 64, 16)
-    got = ops.lowrank_matmul(qa, qw, jnp.asarray(fac.u), jnp.asarray(fac.v))
+    got = _lowrank(qa, qw, jnp.asarray(fac.u), jnp.asarray(fac.v))
     want = ref.approx_matmul_lut_ref(qa, qw, jnp.asarray(lut)
                                      ).astype(jnp.float32)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),

@@ -154,12 +154,11 @@ def test_error_feedback_accumulates():
 
 
 def test_compressed_psum_single_device():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = jax.make_mesh((1,), ("pod",))
     g = {"w": jnp.asarray([0.5, -2.0, 3.0], jnp.float32)}
-    f = shard_map(lambda t: compressed_psum(t, "pod"), mesh=mesh,
-                  in_specs=(P(),), out_specs=P())
+    f = jax.shard_map(lambda t: compressed_psum(t, "pod"), mesh=mesh,
+                      in_specs=(P(),), out_specs=P())
     out = f(g)
     np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(g["w"]),
                                rtol=0.02, atol=0.02)
