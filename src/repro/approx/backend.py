@@ -158,40 +158,45 @@ def _quantized_matmul(x2d: jax.Array, w: jax.Array,
     # baseline; 12/16 for composed wide entries, DESIGN.md §2.6).  May
     # be a traced per-lane scalar inside a mixed-width banked eval.
     bits = backend.consts.get("bits", 8)
-    qp_a = calibrate(x2d, bits=bits)
-    qp_w = calibrate(w, bits=bits)
     # The datapath's integer result is exact on every backend.  Fencing
     # it (codes in, accumulator out) keeps the float code on both sides
     # compiled alike whichever datapath fills the fence: on a TPU, XLA
     # otherwise fuses quantize/dequant into the datapath's own ops
     # differently per datapath, and the rounding of what follows drifts.
-    qa, qw = jax.lax.optimization_barrier(
-        (quantize(x2d, qp_a), quantize(w, qp_w)))
-    za, zw = qp_a.zero_point, qp_w.zero_point
-    k = x2d.shape[1]
+    # The name scopes ``quantize`` and ``dequant`` mark the float code
+    # on each side in the compiled program.
+    with jax.named_scope("quantize"):
+        qp_a = calibrate(x2d, bits=bits)
+        qp_w = calibrate(w, bits=bits)
+        qa, qw = jax.lax.optimization_barrier(
+            (quantize(x2d, qp_a), quantize(w, qp_w)))
     s = jax.lax.optimization_barrier(dp.forward_q(qa, qw, backend.consts))
-    if dp.exact_int32:
-        # exact datapath: Σ (qa-za)(qw-zw) with int32 accumulation
-        row = jnp.sum(qa, axis=1, dtype=jnp.int32)        # (M,)
-        col = jnp.sum(qw, axis=0, dtype=jnp.int32)        # (N,)
-        acc = (s - zw * row[:, None] - za * col[None, :]
-               + k * za * zw).astype(jnp.float32)
-    else:
-        s = s.astype(jnp.float32)
-        row = jnp.sum(qa, axis=1, dtype=jnp.int32).astype(jnp.float32)
-        col = jnp.sum(qw, axis=0, dtype=jnp.int32).astype(jnp.float32)
-        zaf, zwf = za.astype(jnp.float32), zw.astype(jnp.float32)
-        # trunc is an exact identity on these integer-valued products
-        # but pins each one to its own f32 rounding, so XLA/LLVM cannot
-        # contract mul+sub into a single-rounding FMA — without it the
-        # result depends on the surrounding compilation context and the
-        # variants stop being bit-identical (see kernels/fused_matmul
-        # ``_dequant`` for the full rationale).
-        t_row = jnp.trunc(zwf * row[:, None])
-        t_col = jnp.trunc(zaf * col[None, :])
-        t_k = jnp.trunc(k * zaf * zwf)
-        acc = s - t_row - t_col + t_k
-    return acc * (qp_a.scale * qp_w.scale)
+    with jax.named_scope("dequant"):
+        za, zw = qp_a.zero_point, qp_w.zero_point
+        k = x2d.shape[1]
+        if dp.exact_int32:
+            # exact datapath: Σ (qa-za)(qw-zw) with int32 accumulation
+            row = jnp.sum(qa, axis=1, dtype=jnp.int32)        # (M,)
+            col = jnp.sum(qw, axis=0, dtype=jnp.int32)        # (N,)
+            acc = (s - zw * row[:, None] - za * col[None, :]
+                   + k * za * zw).astype(jnp.float32)
+        else:
+            s = s.astype(jnp.float32)
+            row = jnp.sum(qa, axis=1, dtype=jnp.int32).astype(jnp.float32)
+            col = jnp.sum(qw, axis=0, dtype=jnp.int32).astype(jnp.float32)
+            zaf, zwf = za.astype(jnp.float32), zw.astype(jnp.float32)
+            # trunc is an exact identity on these integer-valued
+            # products but pins each one to its own f32 rounding, so
+            # XLA/LLVM cannot contract mul+sub into a single-rounding
+            # FMA — without it the result depends on the surrounding
+            # compilation context and the variants stop being
+            # bit-identical (see kernels/fused_matmul ``_dequant`` for
+            # the full rationale).
+            t_row = jnp.trunc(zwf * row[:, None])
+            t_col = jnp.trunc(zaf * col[None, :])
+            t_k = jnp.trunc(k * zaf * zwf)
+            acc = s - t_row - t_col + t_k
+        return acc * (qp_a.scale * qp_w.scale)
 
 
 # ----------------------------------------------------------------------

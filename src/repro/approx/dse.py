@@ -31,6 +31,7 @@ from typing import Callable, Mapping, Optional, Sequence
 
 import numpy as np
 
+from ..tracing import span
 from . import objectives as objectives_mod
 from .layers import ApproxPolicy, policy_bank_eval, policy_for_lane
 from .objectives import get_objective
@@ -380,6 +381,10 @@ def explore(
     lowest-power all-layers point whose PRIMARY metric stays within
     that drop (direction-aware; see ``objectives.select`` for the
     fully declarative endpoint).
+
+    The call is the span ``explore`` (``repro.tracing``), with the
+    number of multipliers as ``lanes``: the root of every span of its
+    baseline and sweeps.
     """
     wl = as_workload(workload if workload is not None else eval_fn)
     if layer_counts is None:
@@ -397,37 +402,43 @@ def explore(
     if multipliers is None:
         multipliers = [e.name for e in library.case_study_selection()]
     cache = cache if cache is not None else {}
-    run = wl.cached(cache)
-    batch = batch and can_bank(wl, mode, variant)
+    with span("explore", lanes=len(multipliers), per_layer=per_layer):
+        run = wl.cached(cache)
+        batch = batch and can_bank(wl, mode, variant)
 
-    golden = BackendSpec.golden().materialize()
-    baseline_metrics = run.measure(ApproxPolicy(default=golden))
+        with span("explore.baseline"):
+            golden = BackendSpec.golden().materialize()
+            baseline_metrics = run.measure(ApproxPolicy(default=golden))
 
-    result = ExploreResult(
-        baseline_accuracy=baseline_metrics[wl.primary],
-        baseline_metrics=baseline_metrics,
-        objectives=(tuple(objectives) if objectives is not None
-                    else (wl.primary, "power")),
-        primary=wl.primary)
-    if all_layers:
-        rows = all_layers_sweep(wl if batch else run, layer_counts,
-                                multipliers, library, mode=mode,
-                                variant=variant, batch=batch,
-                                sharding=sharding, rel_power=rel_power)
-        if batch:
-            _seed_cache(cache, rows, golden)
-        result.all_layers = [DesignPoint.from_row(r) for r in rows]
-    if per_layer:
-        rows = per_layer_sweep(wl if batch else run, layer_counts,
-                               multipliers, library, mode=mode,
-                               base=golden, variant=variant, batch=batch,
-                               sharding=sharding, rel_power=rel_power)
-        if batch:
-            _seed_cache(cache, rows, golden)
-        result.per_layer = [DesignPoint.from_row(r) for r in rows]
-    if quality_bound is not None and result.all_layers:
-        result.selected = select_multiplier(result, quality_bound)
-    return result
+        result = ExploreResult(
+            baseline_accuracy=baseline_metrics[wl.primary],
+            baseline_metrics=baseline_metrics,
+            objectives=(tuple(objectives) if objectives is not None
+                        else (wl.primary, "power")),
+            primary=wl.primary)
+        if all_layers:
+            rows = all_layers_sweep(wl if batch else run, layer_counts,
+                                    multipliers, library, mode=mode,
+                                    variant=variant, batch=batch,
+                                    sharding=sharding, rel_power=rel_power)
+            with span("sweep.rows"):
+                if batch:
+                    _seed_cache(cache, rows, golden)
+                result.all_layers = [DesignPoint.from_row(r) for r in rows]
+        if per_layer:
+            rows = per_layer_sweep(wl if batch else run, layer_counts,
+                                   multipliers, library, mode=mode,
+                                   base=golden, variant=variant,
+                                   batch=batch, sharding=sharding,
+                                   rel_power=rel_power)
+            with span("sweep.rows"):
+                if batch:
+                    _seed_cache(cache, rows, golden)
+                result.per_layer = [DesignPoint.from_row(r) for r in rows]
+        if quality_bound is not None and result.all_layers:
+            with span("sweep.rows"):
+                result.selected = select_multiplier(result, quality_bound)
+        return result
 
 
 def select_multiplier(result: ExploreResult,

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import fnmatch
 import json
+import re
 import warnings
 from dataclasses import dataclass, field
 from typing import Optional, Union
@@ -24,6 +25,7 @@ from typing import Optional, Union
 import jax
 import jax.numpy as jnp
 
+from ..tracing import span
 from .backend import BackendLike, MatmulBackend, as_backend, backend_matmul
 from .registry import get_datapath
 from .specs import (BackendSpec, LutBank, MaterializedBackend, PolicyBank,
@@ -228,12 +230,38 @@ def bank_eval(fn, bank: LutBank, *, mode: str = "lut",
     ``(n_mult, 256, 256)`` bank) places lanes across devices; see
     ``repro.launch.mesh.bank_sharding``.  Returns ``fn``'s output
     stacked along a new leading ``n_mult`` axis.
+
+    The call is the span ``bank_eval.call`` (``repro.tracing``); the
+    runtime's trace, lowering and load of the program nest inside it.
     """
+    program = "all" if layer_pattern is None else layer_pattern
+    with span("bank_eval.call", program=program):
+        jitted, args = bank_program(fn, bank, mode=mode, variant=variant,
+                                    base=base, layer_pattern=layer_pattern,
+                                    sharding=sharding)
+        return jitted(*args)
+
+
+def bank_program(fn, bank: LutBank, *, mode: str = "lut",
+                 variant: str = "ref",
+                 base: Optional[BackendLike] = None,
+                 layer_pattern: Optional[str] = None,
+                 sharding=None):
+    """The program ``bank_eval`` runs, not yet traced, and its
+    arguments: ``(jitted, args)``, where ``jitted(*args)`` is
+    ``bank_eval``'s result.
+
+    The program is named ``bank_all`` (``layer_pattern=None``) or
+    ``bank_<layer>``, so a compiled module reads ``jit_bank_all``.  Its
+    body is the span ``bank_eval.trace``, which runs only while JAX
+    traces the program: one span per trace."""
     luts = jnp.asarray(bank.luts)
     if sharding is not None:
         luts = jax.device_put(luts, sharding)
     if layer_pattern is not None and base is None:
         base = BackendSpec.golden().materialize()
+    program = "all" if layer_pattern is None else layer_pattern
+    name = "bank_" + re.sub(r"\W", "_", program)
 
     def policy_for(mb):
         if layer_pattern is None:
@@ -257,18 +285,22 @@ def bank_eval(fn, bank: LutBank, *, mode: str = "lut",
                 codes = jax.device_put(codes, aux)
 
         def lane_w(lut, lane_bits, lane_mask, lane_code):
-            mb = _bank_lane_backend(lut, bank, mode, variant,
-                                    mask=lane_mask, bits=lane_bits,
-                                    reduce_code=lane_code)
-            return fn(policy_for(mb))
+            with span("bank_eval.trace", program=program):
+                mb = _bank_lane_backend(lut, bank, mode, variant,
+                                        mask=lane_mask, bits=lane_bits,
+                                        reduce_code=lane_code)
+                return fn(policy_for(mb))
 
-        return jax.jit(jax.vmap(lane_w))(luts, bits, masks, codes)
+        lane_w.__name__ = name
+        return jax.jit(jax.vmap(lane_w)), (luts, bits, masks, codes)
 
     def lane(lut):
-        return fn(policy_for(_bank_lane_backend(lut, bank, mode,
-                                                variant)))
+        with span("bank_eval.trace", program=program):
+            return fn(policy_for(_bank_lane_backend(lut, bank, mode,
+                                                    variant)))
 
-    return jax.jit(jax.vmap(lane))(luts)
+    lane.__name__ = name
+    return jax.jit(jax.vmap(lane)), (luts,)
 
 
 def bank_assignment_overrides(bank: LutBank, luts, assign_row, layers,

@@ -31,13 +31,14 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from ..tracing import span
 from .backend import BackendLike
 from .layers import ApproxPolicy, bank_eval
 from .power import (auto_rel_power, cost_axes_map,
                     network_costs_for_assignment,
                     network_power_for_assignment)
 from .registry import get_datapath
-from .specs import BackendSpec, MaterializedBackend, bank_for
+from .specs import BackendSpec, MaterializedBackend, packed_bank
 from .workload import Workload, as_workload
 
 
@@ -280,28 +281,24 @@ def per_layer_sweep(
     onto a common reference (``power.auto_rel_power``) unless an
     explicit ``rel_power`` map is given.
     """
-    wl = as_workload(eval_fn)
     base = base if base is not None else BackendSpec.golden().materialize()
-    if rel_power is None:
-        rel_power = auto_rel_power(library, multiplier_names)
-    cost_map = cost_axes_map(library, multiplier_names)
-    backends = _backends_for(multiplier_names, library, mode,
-                             variant=variant)
+    wl, rel_power, cost_map, backends, bank = _prepare(
+        eval_fn, multiplier_names, library, mode, variant, batch,
+        rel_power)
     rows = []
     if batch:
-        wl = _require_bankable(wl, mode, variant)
-        bank = bank_for(multiplier_names, library)
         for layer in layer_counts:
             lanes = _unstack_metrics(
                 bank_eval(wl.traceable_metrics, bank, mode=mode,
                           variant=variant, base=base,
                           layer_pattern=layer, sharding=sharding),
                 wl.metrics, len(multiplier_names))
-            for mname, metrics in zip(multiplier_names, lanes):
-                rows.append(_row(library, mname, layer, metrics,
-                                 wl.primary, layer_counts,
-                                 backends[mname].spec, rel_power,
-                                 cost_map))
+            with span("sweep.rows"):
+                for mname, metrics in zip(multiplier_names, lanes):
+                    rows.append(_row(library, mname, layer, metrics,
+                                     wl.primary, layer_counts,
+                                     backends[mname].spec, rel_power,
+                                     cost_map))
         return rows
     for layer in layer_counts:
         for mname, be in backends.items():
@@ -337,23 +334,19 @@ def all_layers_sweep(
     §2.6), with power auto-rebased onto a common reference
     (``power.auto_rel_power``) unless ``rel_power`` overrides it.
     """
-    wl = as_workload(eval_fn)
-    if rel_power is None:
-        rel_power = auto_rel_power(library, multiplier_names)
-    cost_map = cost_axes_map(library, multiplier_names)
-    backends = _backends_for(multiplier_names, library, mode,
-                             variant=variant)
+    wl, rel_power, cost_map, backends, bank = _prepare(
+        eval_fn, multiplier_names, library, mode, variant, batch,
+        rel_power)
     if batch:
-        wl = _require_bankable(wl, mode, variant)
-        bank = bank_for(multiplier_names, library)
         lanes = _unstack_metrics(
             bank_eval(wl.traceable_metrics, bank, mode=mode,
                       variant=variant, sharding=sharding),
             wl.metrics, len(multiplier_names))
-        return [_row(library, mname, "all", metrics, wl.primary,
-                     layer_counts, backends[mname].spec, rel_power,
-                     cost_map)
-                for mname, metrics in zip(multiplier_names, lanes)]
+        with span("sweep.rows"):
+            return [_row(library, mname, "all", metrics, wl.primary,
+                         layer_counts, backends[mname].spec, rel_power,
+                         cost_map)
+                    for mname, metrics in zip(multiplier_names, lanes)]
     rows = []
     for mname, be in backends.items():
         policy = ApproxPolicy(default=be)
@@ -363,11 +356,33 @@ def all_layers_sweep(
     return rows
 
 
+def _prepare(eval_fn, multiplier_names, library, mode: str, variant: str,
+             batch: bool, rel_power):
+    """What a sweep needs before it evaluates, as the span
+    ``sweep.prep``: ``(workload, rel_power, cost_map, backends, bank)``,
+    the bank None unless ``batch``.  The span's ``built`` says whether
+    the bank was packed or came from ``bank_for``'s cache."""
+    with span("sweep.prep") as attrs:
+        wl = as_workload(eval_fn)
+        if rel_power is None:
+            rel_power = auto_rel_power(library, multiplier_names)
+        cost_map = cost_axes_map(library, multiplier_names)
+        backends = _backends_for(multiplier_names, library, mode,
+                                 variant=variant)
+        bank = None
+        if batch:
+            wl = _require_bankable(wl, mode, variant)
+            bank, attrs["built"] = packed_bank(multiplier_names, library)
+    return wl, rel_power, cost_map, backends, bank
+
+
 def _unstack_metrics(out, metric_names, n: int) -> list[dict]:
     """Split a banked evaluation's stacked metric dict ``{metric:
     (n,) array}`` into one float dict per lane, in workload metric
-    order."""
-    arrs = {m: np.asarray(out[m]) for m in metric_names}
+    order.  The copy to the host, where the host waits for the device,
+    is the span ``bank_eval.wait``."""
+    with span("bank_eval.wait"):
+        arrs = {m: np.asarray(out[m]) for m in metric_names}
     return [{m: float(arrs[m][i]) for m in metric_names}
             for i in range(n)]
 

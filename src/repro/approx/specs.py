@@ -582,6 +582,13 @@ def bank_for(names, library=None, block_m: int = 512,
     """LRU-cached ``LutBank.from_library``: repeated sweeps over the
     same candidate set (all-layers then per-layer, or explore() called
     twice) reuse one packed bank instead of restacking LUTs."""
+    return packed_bank(names, library, block_m, mixed_reduce)[0]
+
+
+def packed_bank(names, library=None, block_m: int = 512,
+                mixed_reduce: bool = False) -> tuple[LutBank, bool]:
+    """``bank_for``, and whether the bank was packed by this call
+    (False: it came from the LRU cache)."""
     if library is None:
         from repro.core.library import get_default_library
         library = get_default_library()
@@ -590,13 +597,13 @@ def bank_for(names, library=None, block_m: int = 512,
     hit = _BANK_CACHE.get(key)
     if hit is not None:
         _BANK_CACHE.move_to_end(key)
-        return hit
+        return hit, False
     bank = LutBank.from_library(names, library, block_m=block_m,
                                 mixed_reduce=mixed_reduce)
     _BANK_CACHE[key] = bank
     while len(_BANK_CACHE) > _BANK_CACHE_MAX:
         _BANK_CACHE.popitem(last=False)
-    return bank
+    return bank, True
 
 
 def materialize_cache_stats() -> dict:

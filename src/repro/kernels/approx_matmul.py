@@ -73,18 +73,22 @@ def lut_tables(qw: jax.Array, lut: jax.Array, row_sums: bool = False):
     ``(ceil(K/SUB)*GROUP, Np)`` bf16 with Np = N (+1) rounded up to
     ``BN``, where ``t_lo + 256*t_hi`` at row ``k*256 + v``, column n is
     ``LUT[v, qw[k,n]]``.  ``row_sums=True`` adds column N holding ``v``
-    itself, so the contraction also yields ``Σ_k a[m,k]``."""
+    itself, so the contraction also yields ``Σ_k a[m,k]``.  The ops
+    carry the name scope ``lut_tables``."""
     k, n = qw.shape
     kp = _round_up(k, SUB)
     np_ = _round_up(n + int(row_sums), BN)
-    t = jnp.take(lut, qw, axis=1)                   # (256, K, N)
-    t = jnp.transpose(t, (1, 0, 2))                 # (K, 256, N)
-    if row_sums:
-        v = jnp.arange(256, dtype=jnp.int32)[None, :, None]
-        t = jnp.concatenate([t, jnp.broadcast_to(v, (k, 256, 1))], axis=2)
-    t = jnp.pad(t, ((0, kp - k), (0, 0), (0, np_ - t.shape[2])))
-    t = t.reshape(kp * 256, np_)
-    return (t & 255).astype(jnp.bfloat16), (t >> 8).astype(jnp.bfloat16)
+    with jax.named_scope("lut_tables"):
+        t = jnp.take(lut, qw, axis=1)               # (256, K, N)
+        t = jnp.transpose(t, (1, 0, 2))             # (K, 256, N)
+        if row_sums:
+            v = jnp.arange(256, dtype=jnp.int32)[None, :, None]
+            t = jnp.concatenate([t, jnp.broadcast_to(v, (k, 256, 1))],
+                                axis=2)
+        t = jnp.pad(t, ((0, kp - k), (0, 0), (0, np_ - t.shape[2])))
+        t = t.reshape(kp * 256, np_)
+        return (t & 255).astype(jnp.bfloat16), \
+            (t >> 8).astype(jnp.bfloat16)
 
 
 def _replication(c):
