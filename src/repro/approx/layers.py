@@ -18,7 +18,9 @@ from __future__ import annotations
 import fnmatch
 import json
 import re
+import threading
 import warnings
+import weakref
 from dataclasses import dataclass, field
 from typing import Optional, Union
 
@@ -145,8 +147,9 @@ EXACT_POLICY = ApproxPolicy(default=MatmulBackend(mode="f32"))
 # Banked (vmapped) evaluation — the batched resilience engine's core
 # (DESIGN.md §2.4)
 # ----------------------------------------------------------------------
-def _bank_lane_backend(lut: jax.Array, bank: LutBank, mode: str,
-                       variant: str, mask=None, bits=None,
+def _bank_lane_backend(lut: jax.Array, mode: str, variant: str,
+                       block_m: int, reduce: Optional[str] = None,
+                       mask=None, bits=None,
                        reduce_code=None) -> MaterializedBackend:
     """Backend for ONE vmap lane: a ``mode``-datapath backend whose LUT
     const is a traced ``(256, 256)`` slice of the bank (any datapath
@@ -156,25 +159,32 @@ def _bank_lane_backend(lut: jax.Array, bank: LutBank, mode: str,
     non-differentiable spec argument (the forward math is identical
     either way).
 
-    Width-generic banks (``bank.any_wide``) additionally thread the
-    lane's traced ``bits`` (quantization width) and 2W-bit product
-    ``mask`` (0 = narrow lane) plus the bank's static reduction tree,
-    so one compiled program mixes 8-bit and composed 12/16-bit lanes
-    (DESIGN.md §2.6).  Under the ``fused`` variant the lane's traced
-    ``reduce_code`` rides along too — the fused composed kernel takes
-    the reduction tree as runtime data, which is what lets a
-    mixed-reduce bank compile to one program (DESIGN.md §2.10)."""
+    ``block_m`` is the bank's, and ``reduce`` the static reduction tree
+    of a width-generic bank (``bank.any_wide``; None for an all-8-bit
+    one).  Width-generic lanes additionally thread the lane's traced
+    ``bits`` (quantization width) and 2W-bit product ``mask`` (0 =
+    narrow lane), so one compiled program mixes 8-bit and composed
+    12/16-bit lanes (DESIGN.md §2.6).  Under the ``fused`` variant the
+    lane's traced ``reduce_code`` rides along too — the fused composed
+    kernel takes the reduction tree as runtime data, which is what lets
+    a mixed-reduce bank compile to one program (DESIGN.md §2.10)."""
     dp = get_datapath(mode if variant == "ref" else f"{mode}_{variant}")
     spec = BackendSpec(mode=mode, multiplier="<bank>",
-                       block_m=bank.block_m, ste=False, variant=variant)
-    consts: dict = {"lut": lut, "block_m": bank.block_m}
-    if bank.any_wide:
+                       block_m=block_m, ste=False, variant=variant)
+    consts: dict = {"lut": lut, "block_m": block_m}
+    if reduce is not None:
         from repro.core.families import parse_reduce
         consts.update(composed=True, bits=bits, mask=mask,
-                      reduce=parse_reduce(bank.reduce))
+                      reduce=parse_reduce(reduce))
         if reduce_code is not None:
             consts["reduce_code"] = reduce_code
     return MaterializedBackend(spec=spec, datapath=dp, consts=consts)
+
+
+def _wide_reduce(bank: LutBank) -> Optional[str]:
+    """The static reduction tree a width-generic bank's lanes compile
+    (None for an all-8-bit bank)."""
+    return bank.reduce if bank.any_wide else None
 
 
 def _check_bank_variant(bank: LutBank, variant: str) -> None:
@@ -231,15 +241,27 @@ def bank_eval(fn, bank: LutBank, *, mode: str = "lut",
     ``repro.launch.mesh.bank_sharding``.  Returns ``fn``'s output
     stacked along a new leading ``n_mult`` axis.
 
+    The program is kept for ``fn`` and its static signature while ``fn``
+    lives (``bank_program``): a later bank of other multipliers, of the
+    same signature, runs it again without a trace, lowering or load.
+
     The call is the span ``bank_eval.call`` (``repro.tracing``); the
     runtime's trace, lowering and load of the program nest inside it.
+    Its ``reused`` is true when the program was kept from an earlier
+    call.
     """
     program = "all" if layer_pattern is None else layer_pattern
-    with span("bank_eval.call", program=program):
-        jitted, args = bank_program(fn, bank, mode=mode, variant=variant,
-                                    base=base, layer_pattern=layer_pattern,
-                                    sharding=sharding)
+    with span("bank_eval.call", program=program) as attrs:
+        jitted, args, attrs["reused"] = _bank_program(
+            fn, bank, mode=mode, variant=variant, base=base,
+            layer_pattern=layer_pattern, sharding=sharding)
         return jitted(*args)
+
+
+#: the banked programs of each traced function, by static signature
+#: (``_bank_program``); an entry goes when its function is freed
+_PROGRAMS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+_PROGRAMS_LOCK = threading.Lock()
 
 
 def bank_program(fn, bank: LutBank, *, mode: str = "lut",
@@ -247,60 +269,98 @@ def bank_program(fn, bank: LutBank, *, mode: str = "lut",
                  base: Optional[BackendLike] = None,
                  layer_pattern: Optional[str] = None,
                  sharding=None):
-    """The program ``bank_eval`` runs, not yet traced, and its
-    arguments: ``(jitted, args)``, where ``jitted(*args)`` is
-    ``bank_eval``'s result.
+    """The program ``bank_eval`` runs, and its arguments:
+    ``(jitted, args)``, where ``jitted(*args)`` is ``bank_eval``'s
+    result.
+
+    The bank enters the program only as arguments: its LUTs, and a
+    width-generic bank's per-lane bits, masks and reduce codes.  All
+    else the trace reads is the static signature: ``fn``, ``mode``,
+    ``variant``, ``layer_pattern``, ``base`` (the object),
+    ``bank.block_m`` and, for a width-generic bank, ``bank.reduce``.
+    One ``jitted`` is kept per ``fn`` and signature for as long as
+    ``fn`` lives, so a later bank of the same signature gets the same
+    object and JAX's cache of it skips the trace, lowering and load (a
+    bank of another lane count or sharding is traced again in it).
+    ``fn`` is held weakly: keep it alive while calling ``jitted``.
 
     The program is named ``bank_all`` (``layer_pattern=None``) or
     ``bank_<layer>``, so a compiled module reads ``jit_bank_all``.  Its
     body is the span ``bank_eval.trace``, which runs only while JAX
     traces the program: one span per trace."""
+    return _bank_program(fn, bank, mode=mode, variant=variant, base=base,
+                         layer_pattern=layer_pattern,
+                         sharding=sharding)[:2]
+
+
+def _bank_program(fn, bank: LutBank, *, mode: str, variant: str,
+                  base: Optional[BackendLike],
+                  layer_pattern: Optional[str], sharding):
+    """``bank_program``'s ``(jitted, args)``, and whether ``jitted`` was
+    kept from an earlier call."""
+    _check_bank_variant(bank, variant)
+    if layer_pattern is None:
+        base = None                     # the all-layers policy reads none
+    elif base is None:
+        base = BackendSpec.golden().materialize()
+    reduce = _wide_reduce(bank)
+    # ``base`` by identity; the entry holds it, so its id stays its own
+    key = (mode, variant, layer_pattern, id(base), bank.block_m, reduce)
+    with _PROGRAMS_LOCK:
+        try:
+            programs, fn_ref = _PROGRAMS.setdefault(fn, {}), weakref.ref(fn)
+        except TypeError:       # unhashable or not weakly referable
+            programs, fn_ref = {}, lambda: fn
+        entry = programs.get(key)
+        reused = entry is not None
+        if not reused:
+            entry = programs[key] = (_new_bank_program(
+                fn_ref, mode, variant, base, layer_pattern, bank.block_m,
+                reduce), base)
+    jitted = entry[0]
+
     luts = jnp.asarray(bank.luts)
     if sharding is not None:
         luts = jax.device_put(luts, sharding)
-    if layer_pattern is not None and base is None:
-        base = BackendSpec.golden().materialize()
+    if reduce is None:
+        return jitted, (luts,), reused
+    # mixed-width bank: per-lane quantization width + product mask
+    # (selector + 2W-bit truncation) and reduce code ride the vmapped
+    # axis (DESIGN.md §2.6, §2.10)
+    aux = (jnp.asarray(bank.lane_bits, jnp.int32),
+           jnp.asarray(bank.lane_masks, jnp.uint32),
+           jnp.asarray(bank.lane_reduce_codes, jnp.int32))
+    aux_sharding = None if sharding is None else _lane_sharding(sharding)
+    if aux_sharding is not None:
+        aux = tuple(jax.device_put(a, aux_sharding) for a in aux)
+    return jitted, (luts, *aux), reused
+
+
+def _new_bank_program(fn_ref, mode: str, variant: str, base,
+                      layer_pattern: Optional[str], block_m: int,
+                      reduce: Optional[str]):
+    """``jit(vmap(lane))`` for one static signature.  The lane reads
+    only these values and its arguments, never a ``LutBank``, so the
+    program serves every bank of the signature and keeps none alive."""
     program = "all" if layer_pattern is None else layer_pattern
-    name = "bank_" + re.sub(r"\W", "_", program)
 
-    def policy_for(mb):
-        if layer_pattern is None:
-            return ApproxPolicy(default=mb)
-        return ApproxPolicy(default=base,
-                            overrides=[(layer_pattern, mb)])
-
-    _check_bank_variant(bank, variant)
-    if bank.any_wide:
-        # mixed-width bank: per-lane quantization width + product mask
-        # (selector + 2W-bit truncation) and reduce code ride the
-        # vmapped axis (DESIGN.md §2.6, §2.10)
-        bits = jnp.asarray(bank.lane_bits, jnp.int32)
-        masks = jnp.asarray(bank.lane_masks, jnp.uint32)
-        codes = jnp.asarray(bank.lane_reduce_codes, jnp.int32)
-        if sharding is not None:
-            aux = _lane_sharding(sharding)
-            if aux is not None:
-                bits = jax.device_put(bits, aux)
-                masks = jax.device_put(masks, aux)
-                codes = jax.device_put(codes, aux)
-
-        def lane_w(lut, lane_bits, lane_mask, lane_code):
-            with span("bank_eval.trace", program=program):
-                mb = _bank_lane_backend(lut, bank, mode, variant,
-                                        mask=lane_mask, bits=lane_bits,
-                                        reduce_code=lane_code)
-                return fn(policy_for(mb))
-
-        lane_w.__name__ = name
-        return jax.jit(jax.vmap(lane_w)), (luts, bits, masks, codes)
-
-    def lane(lut):
+    def lane(lut, *wide):
         with span("bank_eval.trace", program=program):
-            return fn(policy_for(_bank_lane_backend(lut, bank, mode,
-                                                    variant)))
+            bits, mask, code = wide or (None, None, None)
+            mb = _bank_lane_backend(lut, mode, variant, block_m, reduce,
+                                    mask=mask, bits=bits, reduce_code=code)
+            policy = (ApproxPolicy(default=mb) if layer_pattern is None
+                      else ApproxPolicy(default=base,
+                                        overrides=[(layer_pattern, mb)]))
+            fn = fn_ref()
+            if fn is None:
+                raise ReferenceError(
+                    "the traced function of this banked program was "
+                    "freed; keep it alive while calling the program")
+            return fn(policy)
 
-    lane.__name__ = name
-    return jax.jit(jax.vmap(lane)), (luts,)
+    lane.__name__ = "bank_" + re.sub(r"\W", "_", program)
+    return jax.jit(jax.vmap(lane))
 
 
 def bank_assignment_overrides(bank: LutBank, luts, assign_row, layers,
@@ -326,13 +386,13 @@ def bank_assignment_overrides(bank: LutBank, luts, assign_row, layers,
             # variant, reduce code) alongside the tile LUT
             # (DESIGN.md §2.6, §2.10)
             mb = _bank_lane_backend(
-                lut, bank, mode, variant,
+                lut, mode, variant, bank.block_m, _wide_reduce(bank),
                 mask=jnp.take(lane_masks, assign_row[j]),
                 bits=jnp.take(lane_bits, assign_row[j]),
                 reduce_code=(None if lane_codes is None else
                              jnp.take(lane_codes, assign_row[j], axis=0)))
         else:
-            mb = _bank_lane_backend(lut, bank, mode, variant)
+            mb = _bank_lane_backend(lut, mode, variant, bank.block_m)
         overrides.append((layer, mb))
     return overrides
 

@@ -177,8 +177,9 @@ def test_spec_fused_matches_ref_variant(rng, lib, mult, bw):
     np.testing.assert_array_equal(outs["ref"], outs["fused"])
 
 
-@pytest.fixture(scope="module")
-def toy_eval(rng):
+def _toy_eval(rng):
+    """A two-layer toy eval: its traceable core, a sequential leg and
+    the list its core appends to once per trace."""
     x = jnp.asarray(rng.normal(size=(8, 16)), jnp.float32)
     w_a = jnp.asarray(rng.normal(size=(16, 16)), jnp.float32)
     w_b = jnp.asarray(rng.normal(size=(16, 4)), jnp.float32)
@@ -196,6 +197,11 @@ def toy_eval(rng):
         return float(jax.jit(lambda: traceable(policy))())
 
     return traceable, sequential, traces
+
+
+@pytest.fixture(scope="module")
+def toy_eval(rng):
+    return _toy_eval(rng)
 
 
 MIXED = ["mul8u_exact", "mul8u_trunc6", N16, N12]
@@ -254,10 +260,11 @@ def test_policy_bank_fused_bit_identical(lib, toy_eval):
 # ----------------------------------------------------------------------
 # trace-count gates: banked fused sweeps are O(1) compiled programs
 # ----------------------------------------------------------------------
-def test_fused_bank_sweep_single_trace(lib, toy_eval):
-    traceable, _, traces = toy_eval
+def test_fused_bank_sweep_single_trace(lib):
+    # a traceable of its own: the module's shared one already has its
+    # banked program, which bank_eval runs again without a trace
+    traceable, _, traces = _toy_eval(np.random.default_rng(0))
     bank = bank_for(MIXED, lib)
-    traces.clear()
     bank_eval(traceable, bank, variant="fused")
     assert len(traces) == 1, (
         f"mixed-width fused bank sweep traced the model "
