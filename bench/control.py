@@ -47,6 +47,7 @@ def main(argv=None) -> int:
 
     from bench import cells, correct, device
     from bench.generators.sweep import Sweep
+    from bench.netlist import tables
     from repro.launch.compile_cache import enable_compile_cache
 
     cell = cells.Cell(args.workload)
@@ -63,25 +64,16 @@ def main(argv=None) -> int:
         for mult, layer, met in sweep.run_bank(k):
             program[(mult, layer)] = met
     library, names = sweep.library, list(sweep.order)
-    layers = [None] + (list(sweep.workload.layer_counts)
-                       if traffic["per_layer"] else [])
+    layers = sweep.programs_per_bank()
     sweep.close()
     print(f"[control] program: {len(program)} rows in "
           f"{time.monotonic() - t0:.1f} s", flush=True)
 
     refmod = importlib.import_module(f"bench.references.{cfg['family']}")
     params = sweep.family.make_params(cfg, ROOT)
-    n, b = traffic["eval_images"], traffic["eval_batch"]
-    ref = refmod.Reference(cfg, params, n, b)
-    ctl = refmod.Reference(cfg, params, n, b, dtype=jnp.bfloat16)
-    luts: dict = {}
-
-    def lut_of(name):
-        if name not in luts:
-            nl = library.entry(name).netlist
-            luts[name] = refmod.netlist_lut(nl.n_i, nl.funcs, nl.in0,
-                                            nl.in1, nl.outputs)
-        return luts[name]
+    ref = refmod.Reference(cfg, params, traffic)
+    ctl = refmod.Reference(cfg, params, traffic, dtype=jnp.bfloat16)
+    lut_of = tables(library)
 
     ref_rows: dict = {}
     ctl_rows: dict = {}

@@ -5,12 +5,17 @@ A traffic mix (``bench/traffic/<name>.json``) gives:
 * ``library``, ``multiplier_prefix``: the library budget the program
   builds from its own seed, and which of its entries are screened;
 * ``bank_lanes``: multipliers per bank; the banks cut the library in
-  the run's order, wrapping around;
+  the run's order, wrapping around (within a bank too, where a bank is
+  wider than the library);
+* ``lane_shards`` (1 where absent): chips each bank's lanes are split
+  over, ``bank_lanes / lane_shards`` to a chip: one program over that
+  many devices (``repro.launch.mesh.bank_sharding``);
 * ``mode``, ``variant``, ``per_layer``, ``quality_bound``: the
   arguments of ``repro.approx.dse.explore`` (``per_layer`` false is
   the Table II sweep alone, true adds the Fig. 4 sweep);
-* ``eval_images``, ``eval_batch``: the eval set of every row;
-* ``check_rows``: rows the comparison with the reference samples.
+* ``check_rows``: rows the comparison with the reference samples;
+* what the configuration's family reads (``bench/models/<family>.py``),
+  such as the size of the eval set and of its batches.
 
 The run's seed fixes only the order in which the library's multipliers
 enter the banks.  Every seed runs the same programs on banks of the
@@ -27,6 +32,24 @@ from __future__ import annotations
 import importlib
 
 import numpy as np
+
+
+def lane_sharding(traffic: dict):
+    """The sharding of a bank's lane axis over the mix's
+    ``lane_shards`` devices, or None for one device."""
+    import jax
+
+    shards = traffic.get("lane_shards", 1)
+    if shards == 1:
+        return None
+    if traffic["bank_lanes"] % shards:
+        raise ValueError(f"{traffic['bank_lanes']} bank lanes do not split "
+                         f"into {shards} shards")
+    if len(jax.devices()) < shards:
+        raise ValueError(f"{shards} lane shards need as many devices; JAX "
+                         f"found {len(jax.devices())}")
+    from repro.launch.mesh import bank_sharding, sweep_mesh
+    return bank_sharding(traffic["bank_lanes"], sweep_mesh(shards))
 
 
 class Sweep:
@@ -50,9 +73,10 @@ class Sweep:
                  if n.startswith(t["multiplier_prefix"])]
         rng = np.random.default_rng(self.seed)
         self.order = [names[i] for i in rng.permutation(len(names))]
+        self.sharding = lane_sharding(t)
         self.params = self.family.make_params(self.cfg, self.root)
-        self.workload = self.family.program_workload(
-            self.cfg, self.params, t["eval_images"], t["eval_batch"])
+        self.workload = self.family.program_workload(self.cfg, self.params,
+                                                     t)
         self.golden_cache: dict = {}
         explore(self.workload, self.workload.layer_counts, self.library,
                 multipliers=self.bank(0), all_layers=False, per_layer=False,
@@ -76,7 +100,7 @@ class Sweep:
         res = explore(self.workload, self.workload.layer_counts,
                       self.library, multipliers=self.bank(k), mode=t["mode"],
                       variant=t["variant"], batch=True,
-                      per_layer=t["per_layer"],
+                      sharding=self.sharding, per_layer=t["per_layer"],
                       quality_bound=t["quality_bound"],
                       cache=dict(self.golden_cache))
         return [(p.multiplier, p.layer, dict(p.metrics))
@@ -84,4 +108,4 @@ class Sweep:
 
     def close(self) -> None:
         """Drop the program's state before the reference runs."""
-        del self.workload, self.params, self.golden_cache
+        del self.workload, self.params, self.golden_cache, self.sharding

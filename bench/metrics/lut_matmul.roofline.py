@@ -1,10 +1,11 @@
 """Share of its roofline that the banked LUT-matmul kernel reaches
 (layer "kernels"): the least time the chip could take for every LUT
-matmul launch of the window, each ``max(operations / int8 peak, bytes /
-HBM bandwidth)`` (``bench/count.py``: the work a LUT matmul needs, not
-the one-hot MXU work that implements it), over the device time of the
-kernel's events in the trace.  The kernel's events are found by name;
-the XLA gathers that build its tables are outside this time."""
+matmul launch of the window on every chip, each ``max(operations / int8
+peak, bytes / HBM bandwidth)`` (``bench/count.py``: the work a LUT
+matmul needs, not the one-hot MXU work that implements it), over the
+device time of the kernel's events in the trace, summed over the
+chips.  The kernel's events are found by name; the XLA gathers that
+build its tables are outside this time."""
 
 #: the banked Pallas kernel of ``repro/kernels/approx_matmul.py`` as it
 #: appears in the trace: a TPU custom call named after its jitted wrapper

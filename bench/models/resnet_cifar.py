@@ -7,6 +7,11 @@
 * ``program_workload``: the program's own ``classification`` workload
   (``fidelity=True``: rows carry ``logit_mae`` and ``accuracy``) over
   those weights, on the eval images the traffic mix asks for.
+
+Every family's module defines these two, and its reference
+(``bench/references/<family>.py``) a ``Reference(cfg, params, traffic,
+dtype)`` whose ``row(lut, layer)`` and ``logit_scale()`` the comparison
+reads (``bench/correct.py``).
 """
 from __future__ import annotations
 
@@ -75,8 +80,9 @@ def make_params(cfg: dict, root: str):
     raise ValueError(f"unknown weights kind {w['kind']!r}")
 
 
-def program_workload(cfg: dict, params, eval_images: int, eval_batch: int):
-    """The program's classification workload of this configuration."""
+def program_workload(cfg: dict, params, traffic: dict):
+    """The program's classification workload of this configuration, on
+    the mix's ``eval_images`` in batches of ``eval_batch``."""
     from repro.approx.workload import classification
     from repro.models.resnet import ResNetConfig
 
@@ -84,8 +90,8 @@ def program_workload(cfg: dict, params, eval_images: int, eval_batch: int):
                         n_classes=cfg["n_classes"],
                         image_size=cfg["image_size"],
                         norm_eps=cfg["norm_eps"])
-    wl = classification(rcfg, params, eval_n=eval_images, batch=eval_batch,
-                        fidelity=True)
+    wl = classification(rcfg, params, eval_n=traffic["eval_images"],
+                        batch=traffic["eval_batch"], fidelity=True)
     if list(wl.layer_counts) != layer_names(cfg)[:-1]:
         raise ValueError(f"the program's layers {list(wl.layer_counts)} "
                          f"are not the configuration's {layer_names(cfg)}")

@@ -2,8 +2,9 @@
 
 It imports nothing of the program and takes nothing the program made:
 the eval images come from a copy of the synthetic-CIFAR generator, each
-multiplier's product table from a simulation of its gate netlist, and
-the weights from the benchmark (``bench/models/resnet_cifar.py``).
+multiplier's product table from a simulation of its gate netlist
+(``bench/netlist.py``), and the weights from the benchmark
+(``bench/models/resnet_cifar.py``).
 
 A row is one multiplier evaluated on the eval images, in every layer
 (Table II) or in one layer with every other layer exact (Fig. 4).  Its
@@ -35,52 +36,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-#: gate function codes of the CGP function set, in the paper's Fig. 1
-#: order: identity, not, and, or, xor, nand, nor, xnor, const0, const1
-_GATES = (
-    lambda a, b: a,
-    lambda a, b: ~a,
-    lambda a, b: a & b,
-    lambda a, b: a | b,
-    lambda a, b: a ^ b,
-    lambda a, b: ~(a & b),
-    lambda a, b: ~(a | b),
-    lambda a, b: ~(a ^ b),
-    lambda a, b: np.zeros_like(a),
-    lambda a, b: np.ones_like(a),
-)
-_ARITY = (1, 1, 2, 2, 2, 2, 2, 2, 0, 0)
+from bench.netlist import exact_lut
+
 #: rows of the product table gathered at once (bounds the gather's
 #: intermediate to rows * K * N int32)
 _ROW_BLOCK = 1024
-
-
-def netlist_lut(n_i: int, funcs, in0, in1, outputs) -> np.ndarray:
-    """(256, 256) int32 product table of an 8x8-bit multiplier netlist:
-    every node evaluated on all 2^16 input pairs.  Input bit ``i < 8``
-    is bit ``i`` of the row operand, input ``8 + i`` bit ``i`` of the
-    column operand; output ``k`` is bit ``k`` of the product.  A gate
-    reads only the inputs its function takes: the unused input fields
-    of a stored netlist may point anywhere."""
-    if n_i != 16:
-        raise ValueError(f"an 8x8-bit multiplier has 16 inputs, not {n_i}")
-    v = np.arange(1 << 16, dtype=np.int64)
-    sig = [((v >> i) & 1).astype(bool) for i in range(n_i)]
-    for f, a, b in zip(funcs, in0, in1):
-        arity = _ARITY[int(f)]
-        x = sig[int(a)] if arity >= 1 else sig[0]
-        y = sig[int(b)] if arity == 2 else x
-        sig.append(_GATES[int(f)](x, y))
-    out = np.zeros(1 << 16, np.int64)
-    for k, s in enumerate(outputs):
-        out |= sig[int(s)].astype(np.int64) << k
-    # v = row + 256 * col
-    return out.reshape(256, 256).T.astype(np.int32)
-
-
-def exact_lut() -> np.ndarray:
-    a = np.arange(256, dtype=np.int32)
-    return a[:, None] * a[None, :]
 
 
 def synthetic_cifar(split: str, n: int, seed: int = 0, image_size: int = 32,
@@ -262,12 +222,13 @@ def forward(params, images, luts, sel, cfg: dict, dtype=jnp.float32):
 
 class Reference:
     """The reference (``dtype=float32``) or its lower-precision control
-    over one configuration's eval images.  ``rows`` evaluates rows
+    over the eval images the traffic mix asks for.  ``rows`` evaluates rows
     ``(multiplier LUT, layer or "all")``; one compiled program serves
     every row, with the LUTs and the layer choice as its arguments."""
 
-    def __init__(self, cfg: dict, params, n_images: int, batch: int,
+    def __init__(self, cfg: dict, params, traffic: dict,
                  dtype=jnp.float32):
+        n_images, batch = traffic["eval_images"], traffic["eval_batch"]
         images, labels = synthetic_cifar("test", n_images)
         nb = n_images // batch
         self.cfg = cfg

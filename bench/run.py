@@ -20,7 +20,8 @@ the mix names its generator (``bench/generators/<name>.py``).  A run:
    compile inside the window (``bench/monitor.py``) fails the run;
 4. with ``--trace 1`` the window is traced by the JAX profiler and the
    per-layer metrics (``bench/metrics/<name>.py``) are read from the
-   trace, the runtime's compile spans and the counts of ``count.py``;
+   trace, the program's and the runtime's spans and the counts of the
+   configuration's family (``bench/counts/<family>.py``);
 5. reads the device's peak memory, frees the program's state, and
    compares a sample of the window's rows with the plain reference
    (``bench/correct.py``).
@@ -61,8 +62,8 @@ class Measured:
     rows: int
     chips: int
     peaks: dict
-    macs_per_image: int
-    eval_images: int
+    macs_per_item: int
+    eval_items: int
     lut_calls: list
     host: dict
     trace: object = None
@@ -83,18 +84,19 @@ def load_reader(name: str):
 
 def lut_calls(cfg: dict, traffic: dict, programs: list, banks: int) -> list:
     """``(operations, bytes)`` of every LUT matmul launch of ``banks``
-    completed banks: each program runs each of its LUT matmuls once per
-    eval batch, for all the bank's lanes in one launch."""
-    from bench.count import lut_matmul_work, program_lut_matmuls
+    completed banks, on every chip: each chip launches each of a
+    program's LUT matmuls (``bench/counts/<family>.py``) once, for the
+    ``bank_lanes / lane_shards`` lanes it holds.  The trace's kernel
+    time is summed over the chips alike (``bench/trace.py``)."""
+    from bench.count import family, lut_matmul_work
 
-    per_bank = []
-    for layer in programs:
-        for _, m, k, n, shared in program_lut_matmuls(
-                cfg, traffic["eval_batch"], layer):
-            per_bank.append(lut_matmul_work(m, k, n, shared,
-                                            traffic["bank_lanes"]))
-    batches = traffic["eval_images"] // traffic["eval_batch"]
-    return per_bank * (batches * banks)
+    shards = traffic.get("lane_shards", 1)
+    lanes = traffic["bank_lanes"] // shards
+    per_chip = [lut_matmul_work(m, k, n, shared, lanes)
+                for layer in programs
+                for _, m, k, n, shared in family(cfg).program_lut_matmuls(
+                    cfg, traffic, layer)]
+    return per_chip * (shards * banks)
 
 
 def breakdown(trace, host_spans, offset_s) -> dict:
@@ -133,8 +135,9 @@ def run_cell(cell, devices, seed: int, seconds: float, trace: bool):
     import jax
 
     from bench import correct, device
-    from bench.count import macs_per_image
+    from bench.count import family
     from bench.monitor import Monitor
+    from bench.netlist import tables
     from repro.launch.compile_cache import enable_compile_cache
 
     peaks = device.peaks(devices[0].device_kind)
@@ -186,8 +189,8 @@ def run_cell(cell, devices, seed: int, seconds: float, trace: bool):
 
     run = Measured(window_s=window_s, window_host=window_host,
                    rows=len(rows), chips=cell.chips, peaks=peaks,
-                   macs_per_image=macs_per_image(cfg),
-                   eval_images=traffic["eval_images"],
+                   macs_per_item=family(cfg).macs_per_item(cfg),
+                   eval_items=family(cfg).eval_items(cfg, traffic),
                    lut_calls=lut_calls(cfg, traffic, programs, k),
                    host=host)
     result_metrics, extra = {}, {}
@@ -217,16 +220,9 @@ def run_cell(cell, devices, seed: int, seconds: float, trace: bool):
     jax.clear_caches()
     refmod = importlib.import_module(f"bench.references.{cfg['family']}")
     t_ref = time.monotonic()
-    reference = refmod.Reference(cfg, run_params(cfg), traffic["eval_images"],
-                                 traffic["eval_batch"])
-
-    def lut_of(name):
-        nl = library.entry(name).netlist
-        return refmod.netlist_lut(nl.n_i, nl.funcs, nl.in0, nl.in1,
-                                  nl.outputs)
-
+    reference = refmod.Reference(cfg, run_params(cfg), traffic)
     picks = correct.sample(rows, traffic["check_rows"], seed)
-    numbers = correct.compare(rows, picks, reference, lut_of)
+    numbers = correct.compare(rows, picks, reference, tables(library))
     ok, checks = correct.judge(numbers, cell.limits)
     log(f"[bench] reference: {len(picks)} rows "
         f"{[rows[i][:2] for i in picks]} in "

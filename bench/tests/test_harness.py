@@ -43,8 +43,9 @@ def test_entry_keys_and_text():
                     assert "\n" not in entry[k] and "\t" not in entry[k]
             if group in ("end_to_end", "per_layer"):
                 assert entry["better"] in ("lower", "higher")
-    for w in BENCH["workloads"]:
-        assert w["chips"] == 1
+    chips = [w["chips"] for w in BENCH["workloads"]]
+    assert set(chips) <= {1, 4}
+    assert chips.count(4) <= max(1, len(chips) // 2)
     for c in BENCH["configs"]:
         assert os.path.exists(os.path.join(ROOT, c["file"]))
         assert c["file"].startswith("bench/configs/")
@@ -57,8 +58,10 @@ def test_cell_files_found_by_name(name):
     assert cell.config["family"] and cell.traffic["generator"]
     for f in (f"bench/models/{cell.config['family']}.py",
               f"bench/references/{cell.config['family']}.py",
+              f"bench/counts/{cell.config['family']}.py",
               f"bench/generators/{cell.traffic['generator']}.py"):
         assert os.path.exists(os.path.join(ROOT, f)), f
+    assert cell.traffic.get("lane_shards", 1) <= cell.chips
     assert set(cell.limits) == {"logit_mae_gap"}
     names = {m["name"] for m in cell.end_to_end}
     assert "setup_s" in names and len(names) >= 2

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import cells, correct, device
+from bench import cells, correct, device, netlist
 from bench import run as bench_run
 from bench.references import resnet_cifar as ref
 
@@ -88,24 +88,21 @@ def test_broken_run_is_not_correct(cpu, monkeypatch, fault):
     assert not out["correct"], out["checks"]
 
 
-def test_control_fails_the_comparison():
+@pytest.mark.parametrize("name", ["resnet8_cifar.table2",
+                                  "resnet8_cifar.table2.ref.x4"])
+def test_control_fails_the_comparison(name):
     """The reference computed in bfloat16, put in the program's place,
     on the sample a run draws: its numbers exceed the limits."""
-    cell = tiny("resnet8_cifar.table2", check_rows=4)
+    cell = tiny(name, check_rows=4)
     from repro.core.library import build_default_library
     from bench.models.resnet_cifar import make_params
     lib = build_default_library("tiny")
     names = [n for n in lib.entries if n.startswith("mul8u_")]
     params = make_params(cell.config, cells.ROOT)
-    t = cell.traffic
-    want = ref.Reference(cell.config, params, t["eval_images"],
-                         t["eval_batch"])
-    control = ref.Reference(cell.config, params, t["eval_images"],
-                            t["eval_batch"], dtype=jnp.bfloat16)
-
-    def lut_of(name):
-        nl = lib.entry(name).netlist
-        return ref.netlist_lut(nl.n_i, nl.funcs, nl.in0, nl.in1, nl.outputs)
+    want = ref.Reference(cell.config, params, cell.traffic)
+    control = ref.Reference(cell.config, params, cell.traffic,
+                            dtype=jnp.bfloat16)
+    lut_of = netlist.tables(lib)
 
     rng = np.random.default_rng(SEED)
     rows = [(n, "all", control.row(lut_of(n), "all"))
@@ -122,9 +119,10 @@ def test_netlist_tables_match_the_library():
     for name in ("mul8u_exact", "mul8u_trunc5", "mul8u_bam_h2_v7",
                  [n for n in lib.entries if n.startswith("mul8u_E")][0]):
         nl = lib.entry(name).netlist
-        got = ref.netlist_lut(nl.n_i, nl.funcs, nl.in0, nl.in1, nl.outputs)
+        got = netlist.netlist_lut(nl.n_i, nl.funcs, nl.in0, nl.in1,
+                                  nl.outputs)
         assert np.array_equal(got, lib.lut(name)), name
-    assert np.array_equal(ref.exact_lut(), lib.lut("mul8u_exact"))
+    assert np.array_equal(netlist.exact_lut(), lib.lut("mul8u_exact"))
 
 
 def test_eval_images_match_the_program():

@@ -17,7 +17,7 @@ work.  The harness marks the window with a ``TraceAnnotation`` named
   named by the HLO instruction without its number, summed over the
   devices;
 * ``kernel_s(*patterns)``: seconds of the operations whose HLO text
-  contains every pattern;
+  contains every pattern, summed over the devices;
 * ``idle_gaps``: the intervals inside the window in which no device
   operation ran, each named by the host activity that covers it
   (spans given on the host clock with an offset to trace time).
